@@ -2,12 +2,17 @@
 from __future__ import annotations
 
 import time
+from pathlib import Path
 from typing import Callable, Dict, List
 
 import numpy as np
 import jax.numpy as jnp
 
 ROWS: List[Dict] = []
+
+# persistent compile cache: a fixed path inside the checkout (a directory
+# that moves never hits); JAX_COMPILATION_CACHE_DIR overrides it
+CACHE_DIR = str(Path(__file__).resolve().parent.parent / ".jax_cache")
 
 
 def emit(name: str, value: float, derived: str = ""):

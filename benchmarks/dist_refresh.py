@@ -1,7 +1,9 @@
 """Distributed fine-grain refresh vs warm re-converge (Fig. 8 on a mesh).
 
-Two meshed sessions receive the identical delta stream on a forced
-8-device CPU mesh:
+Two meshed sessions receive the identical delta stream on a mesh over
+every device the platform has (the four chips of a v5e host; on a CPU
+host, set ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before
+running to get eight virtual devices):
 
   * ``fine`` — ``MeshConfig(refresh="fine")``: delta-only all_to_all +
     per-shard MRBG merges (the tentpole path; auto MRBG-off may still
@@ -20,30 +22,28 @@ land in ``BENCH_dist.json``:
 """
 from __future__ import annotations
 
-import os
+import argparse
+import json
+import time
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+import jax
+import numpy as np
 
-import argparse            # noqa: E402
-import json                # noqa: E402
-import time                # noqa: E402
-
-import jax                 # noqa: E402
-import numpy as np         # noqa: E402
-
-from benchmarks.common import emit                       # noqa: E402
-from jax.sharding import Mesh                            # noqa: E402
-from repro.api import MeshConfig, RunConfig, Session     # noqa: E402
-from repro.apps import pagerank as pr                    # noqa: E402
-from repro.core.incremental import make_delta            # noqa: E402
+from benchmarks.common import emit
+from jax.sharding import Mesh
+from repro.api import MeshConfig, RunConfig, Session
+from repro.apps import pagerank as pr
+from repro.core.incremental import make_delta
 
 
 def _mesh() -> Mesh:
     devs = jax.devices()
-    assert len(devs) >= 8, (
-        "dist_refresh needs XLA_FLAGS=--xla_force_host_platform_device_count=8 "
-        "set before jax initializes")
-    return Mesh(np.array(devs[:8]), ("data",))
+    if len(devs) < 2:
+        raise SystemExit(
+            f"dist_refresh needs a mesh of >= 2 devices, found {len(devs)} "
+            f"{devs[0].platform} device(s); on a CPU host set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count=8 before running")
+    return Mesh(np.array(devs), ("data",))
 
 
 def _graph_delta(mirror: np.ndarray, rng, n_rows: int):
@@ -162,10 +162,10 @@ def main():
 
     backends = (("xla", "pallas") if args.backend == "both"
                 else (args.backend,))
-    results = {"platform": jax.default_backend(),
+    dev = jax.devices()[0]
+    results = {"platform": dev.platform, "device_kind": dev.device_kind,
                "devices": len(jax.devices()),
-               "note": "8 forced CPU host devices; wall-clock includes "
-                       "host merge + device exchange",
+               "note": "wall-clock includes host merge + device exchange",
                "tiny": args.tiny, "graph": {"s": s, "f": f},
                "epochs": epochs, "backends": {}}
     for bk in backends:

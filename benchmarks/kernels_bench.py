@@ -1,5 +1,6 @@
-"""Kernel micro-benchmarks: interpret-mode wall time (correctness-scale) +
-analytic TPU-v5e roofline estimates per kernel (the real perf claim).
+"""Kernel micro-benchmarks: wall time per kernel, plus an analytic roofline
+floor (``tpu_est``) from the published peaks of the device it runs on —
+printed only where ``repro.launch.mesh.PEAKS`` knows the device kind.
 
 Run directly with ``--backend {xla,pallas,both}`` to time the dispatcher hot
 paths (``ops.sort_pairs`` / ``ops.segment_reduce``) plus an end-to-end
@@ -12,10 +13,20 @@ import argparse
 import json
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from benchmarks.common import emit, timed
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS
+from repro.launch.mesh import PEAKS
+
+
+def _roofline(flops: float, nbytes: float) -> str:
+    """``tpu_est=..us,`` on a device with published peaks, else nothing."""
+    peak = PEAKS.get(jax.devices()[0].device_kind)
+    if peak is None:
+        return ""
+    t = max(flops / peak["flops_bf16"], nbytes / peak["hbm_bw"])
+    return f"tpu_est={t * 1e6:.1f}us,"
 
 
 def run():
@@ -29,9 +40,8 @@ def run():
     out, dt = timed(lambda: segment_reduce_mxu(seg, vals, k, rows=512,
                                                kblk=512).block_until_ready())
     flops = 2 * n * 512 * d * (k // 512)
-    tpu_s = max(flops / PEAK_FLOPS, (n * d * 4 + k * d * 4) / HBM_BW)
     emit("kernel.segment_reduce.interp_s", dt * 1e6,
-         f"tpu_est={tpu_s*1e6:.1f}us,flops={flops:.2e}")
+         _roofline(flops, n * d * 4 + k * d * 4) + f"flops={flops:.2e}")
 
     # flash attention
     from repro.kernels.flash_attention import flash_attention
@@ -42,9 +52,8 @@ def run():
     out, dt = timed(lambda: flash_attention(q, kk, v, q_blk=128,
                                             kv_blk=128).block_until_ready())
     flops = 4 * b * h * s * s * hd
-    tpu_s = max(flops / PEAK_FLOPS, 3 * b * h * s * hd * 4 / HBM_BW)
     emit("kernel.flash_attention.interp_s", dt * 1e6,
-         f"tpu_est={tpu_s*1e6:.1f}us,flops={flops:.2e}")
+         _roofline(flops, 3 * b * h * s * hd * 4) + f"flops={flops:.2e}")
 
     # bitonic sort
     from repro.kernels.sort_u32 import sort_kv32
@@ -53,9 +62,8 @@ def run():
     payload = jnp.arange(n, dtype=jnp.int32)
     out, dt = timed(lambda: sort_kv32(keys, payload)[0].block_until_ready())
     stages = int(np.log2(n)) * (int(np.log2(n)) + 1) // 2
-    tpu_s = stages * n * 8 / HBM_BW          # VPU-bound estimate
-    emit("kernel.sort_kv32.interp_s", dt * 1e6,
-         f"tpu_est={tpu_s*1e6:.1f}us,stages={stages}")
+    emit("kernel.sort_kv32.interp_s", dt * 1e6,     # VPU-bound estimate
+         _roofline(0, stages * n * 8) + f"stages={stages}")
 
     # spmv
     from repro.kernels.spmv_ell import spmv_ell
@@ -68,9 +76,8 @@ def run():
                                      rows=256, kblk=1024
                                      ).block_until_ready())
     flops = 2 * s_ * f_ * 1024 * (v_ // 1024)
-    tpu_s = max(flops / PEAK_FLOPS, (s_ * f_ * 8 + v_ * 4) / HBM_BW)
     emit("kernel.spmv_ell.interp_s", dt * 1e6,
-         f"tpu_est={tpu_s*1e6:.1f}us")
+         _roofline(flops, s_ * f_ * 8 + v_ * 4))
 
 
 # ---------------------------------------------------------------------------
@@ -106,8 +113,8 @@ def _bench_ops(backend: str, results: dict) -> None:
 def _sweep_ops(backend: str, sizes, *, repeat: int = 2) -> list:
     """Size sweep of the dispatcher hot paths (2^10..2^20 rows by default).
 
-    Records, per size: the shuffle sort, the segment reduce, and (pallas)
-    the fused vs composed ``shuffle_reduce``.  The point of the sweep is
+    Records, per size: the shuffle sort, the segment reduce, and the
+    composed ``shuffle_reduce``.  The point of the sweep is
     the *shape* of the curves — before the multi-tile sort, pallas fell
     off a cliff past one VMEM tile (pad-to-pow2-of-total); now the cost
     should scale as n log² n with no discontinuity at the old tile limit.
@@ -149,13 +156,6 @@ def _sweep_ops(backend: str, sizes, *, repeat: int = 2) -> list:
         fn()
         _, dt = timed(fn, repeat=repeat)
         rec["shuffle_reduce_us"] = dt * 1e6
-        if backend == "pallas":
-            fn = lambda: ops.shuffle_reduce(
-                _Sum(), k2, mk, vals, valid, sign, keys, backend=backend,
-                fused=False).counts.block_until_ready()
-            fn()
-            _, dt = timed(fn, repeat=repeat)
-            rec["shuffle_reduce_unfused_us"] = dt * 1e6
         emit(f"ops.sweep.{backend}.n{n}.sort_us", rec["sort_us"],
              ",".join(f"{k}={v:.0f}" for k, v in rec.items()
                       if k.endswith("_us") and k != "sort_us"))

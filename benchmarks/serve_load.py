@@ -29,7 +29,7 @@ import json
 import jax
 import numpy as np
 
-from benchmarks.common import emit
+from benchmarks.common import CACHE_DIR, emit
 from repro.serve import ServeTier, SLOClass
 from repro.serve import loadgen
 
@@ -173,7 +173,7 @@ def main():
                     help="CI smoke sizes (seconds, not minutes)")
     ap.add_argument("--out", default=None,
                     help="write BENCH_serve.json here")
-    ap.add_argument("--cache-dir", default=".jax_cache",
+    ap.add_argument("--cache-dir", default=CACHE_DIR,
                     help="persistent XLA executable cache directory "
                          "('' disables)")
     args = ap.parse_args()

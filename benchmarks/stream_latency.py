@@ -31,7 +31,7 @@ import json
 import jax
 import numpy as np
 
-from benchmarks.common import emit
+from benchmarks.common import CACHE_DIR, emit
 from repro.api import RunConfig, StreamConfig
 from repro.apps import pagerank as pr, wordcount as wc
 from repro.kernels import jitcache
@@ -197,7 +197,7 @@ def main():
     ap.add_argument("--out", default=None,
                     help="write BENCH_stream.json here (default: only when "
                          "running --backend both full-size)")
-    ap.add_argument("--cache-dir", default=".jax_cache",
+    ap.add_argument("--cache-dir", default=CACHE_DIR,
                     help="persistent XLA executable cache directory "
                          "('' disables)")
     args = ap.parse_args()

@@ -86,16 +86,13 @@ def oracle(nbrs: np.ndarray, valid_rows=None, iters: int = 200,
     s = nbrs.shape[0]
     if valid_rows is None:
         valid_rows = np.ones(s, bool)
+    live = (nbrs >= 0) & np.asarray(valid_rows, bool)[:, None]
+    deg = live.sum(axis=1)
+    src = np.nonzero(live)[0]
+    dst = nbrs[live]
     r = np.ones(s, np.float64)
     for _ in range(iters):
-        acc = np.zeros(s, np.float64)
-        for i in range(s):
-            if not valid_rows[i]:
-                continue
-            out = nbrs[i][nbrs[i] >= 0]
-            if out.size == 0:
-                continue
-            np.add.at(acc, out, r[i] / out.size)
+        acc = np.bincount(dst, weights=r[src] / deg[src], minlength=s)
         new = DAMPING * acc + (1 - DAMPING)
         done = np.abs(new - r).max() < tol
         r = new

@@ -85,26 +85,24 @@ def make_job(nbrs: np.ndarray, w: np.ndarray, src: int, valid_rows=None):
 
 def oracle(nbrs: np.ndarray, w: np.ndarray, src: int,
            valid_rows=None) -> np.ndarray:
-    """Bellman-Ford reference."""
+    """Bellman-Ford reference (all edges relaxed per round)."""
     s = nbrs.shape[0]
     if valid_rows is None:
         valid_rows = np.ones(s, bool)
+    live = (nbrs >= 0) & np.asarray(valid_rows, bool)[:, None]
+    u = np.nonzero(live)[0]
+    v = nbrs[live]
+    wv = np.asarray(w, np.float64)[live]
     d = np.full(s, np.float64(INF))
     d[src] = 0.0
     for _ in range(s):
-        changed = False
-        for i in range(s):
-            if not valid_rows[i] or d[i] >= INF / 2:
-                continue
-            for jj, jv in enumerate(nbrs[i]):
-                if jv < 0:
-                    continue
-                nd = d[i] + w[i, jj]
-                if nd < d[jv] - 1e-12:
-                    d[jv] = nd
-                    changed = True
-        if not changed:
+        reach = d[u] < INF / 2
+        cand = np.full(s, np.inf)
+        np.minimum.at(cand, v[reach], d[u[reach]] + wv[reach])
+        better = cand < d - 1e-12
+        if not better.any():
             break
+        d = np.where(better, cand, d)
     return d
 
 

@@ -63,11 +63,10 @@ def make_stream(docs: np.ndarray, vocab: int, frac: float = 0.05,
 
 
 def oracle(docs: np.ndarray, vocab: int, valid=None) -> np.ndarray:
-    counts = np.zeros(vocab)
-    for i, d in enumerate(docs):
-        if valid is not None and not valid[i]:
-            continue
-        for w in d:
-            if w >= 0:
-                counts[w] += 1
-    return counts
+    """Per-word counts of the valid documents (float64, like the engine's
+    float32 ``"c"`` sums but exact)."""
+    docs = np.asarray(docs)
+    if valid is not None:
+        docs = docs[np.asarray(valid, bool)]
+    words = docs[docs >= 0]
+    return np.bincount(words, minlength=vocab).astype(np.float64)

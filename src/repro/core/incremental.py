@@ -233,7 +233,7 @@ def _merge_reduce(reducer: Reducer, key_cap: int, backend: Optional[str],
     """
     jitcache.count_trace("incremental._merge_reduce")
     # the whole sort -> last-writer-wins -> segment-reduce chain lives in
-    # ops.shuffle_reduce (fused into one kernel on the pallas backend)
+    # ops.shuffle_reduce
     sr = ops.shuffle_reduce(reducer, combined.k2, combined.mk, combined.v2,
                             combined.valid, combined.sign, affected_keys,
                             backend=backend)

@@ -39,12 +39,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, kv_blk: int, causal: bool,
 
     def body(kv_i, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (slice(0, 1), slice(0, 1),
-                            pl.dslice(kv_i * kv_blk, kv_blk),
-                            slice(None)))[0, 0]
-        v = pl.load(v_ref, (slice(0, 1), slice(0, 1),
-                            pl.dslice(kv_i * kv_blk, kv_blk),
-                            slice(None)))[0, 0]
+        k = k_ref[0, 0, pl.ds(kv_i * kv_blk, kv_blk), :]
+        v = v_ref[0, 0, pl.ds(kv_i * kv_blk, kv_blk), :]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
         s = s / (hd ** 0.5)
         if softcap > 0:

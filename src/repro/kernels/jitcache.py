@@ -16,7 +16,8 @@ survivable:
     XLA backend compiles (a persistent-cache hit traces but does not
     compile, so the two counters differ exactly by the cache's hits).
   * **Persistent compilation cache** — :func:`enable_persistent_cache`
-    points JAX's disk cache at a directory (``RunConfig(
+    points JAX's disk cache at ``JAX_COMPILATION_CACHE_DIR`` when that is
+    set, else at the caller's directory (``RunConfig(
     compilation_cache_dir=...)``), with the entry-size/compile-time
     floors dropped so the many small refresh executables qualify.
     Executables then survive process restarts: a restarted serving node
@@ -26,10 +27,12 @@ survivable:
 from __future__ import annotations
 
 import collections
+import os
 import threading
 from typing import Dict, Optional
 
 import jax
+from jax.experimental.compilation_cache import compilation_cache
 
 _lock = threading.Lock()
 _traces: collections.Counter = collections.Counter()
@@ -102,33 +105,36 @@ def install_compile_listener() -> None:
     jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
 
 
-def enable_persistent_cache(path) -> None:
-    """Point JAX's persistent compilation cache at ``path`` (idempotent).
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
-    Drops the default entry-size and compile-time floors so that the
-    refresh path's many small executables are cached too, and enables the
-    underlying XLA caches on every backend (the CPU leg included).
+
+def enable_persistent_cache(path=None) -> Optional[str]:
+    """Turn on JAX's persistent compilation cache (idempotent).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already caches there
+    and that directory wins: ``path`` is ignored and no other directory is
+    set.  Otherwise the cache goes to ``path`` (nothing happens when it is
+    None).  Either way the default entry-size and compile-time floors are
+    dropped, so that the refresh path's many small executables are cached
+    too, and the underlying XLA caches are enabled.  Returns the directory
+    in use.
     """
     global _cache_dir
-    path = str(path)
-    if _cache_dir == path:
-        return
-    jax.config.update("jax_compilation_cache_dir", path)
+    env = os.environ.get(CACHE_ENV)
+    target = env or (None if path is None else str(path))
+    if target is None or _cache_dir == target:
+        return _cache_dir
+    if not env:
+        jax.config.update("jax_compilation_cache_dir", target)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-    except (AttributeError, ValueError):  # older jax: flag absent
-        pass
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
     # JAX latches the cache-enabled decision at the first compile; if
     # anything compiled before this call (module import commonly does),
-    # the latch must be cleared for the new directory to take effect
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover — internal API moved
-        pass
-    _cache_dir = path
+    # the latch must be cleared for the new settings to take effect
+    compilation_cache.reset_cache()
+    _cache_dir = target
+    return _cache_dir
 
 
 def persistent_cache_dir() -> Optional[str]:

@@ -213,9 +213,21 @@ def _segment_reduce_xla(kind, seg, values, valid, num_segments,
 
 def _segment_reduce_pallas(kind, seg, values, valid, num_segments):
     from repro.kernels.segment_reduce import (
-        segment_minmax_mxu, segment_sum_counts_mxu, segment_sum_mxu,
+        padded_rows, segment_minmax_mxu, segment_sum_counts_mxu,
+        segment_sum_mxu,
     )
     interp = _interpret()
+    # pad the rows while each leaf still has its own shape: the kernels
+    # take [rows, width] columns, and on TPU padding a column that was just
+    # reshaped from a wider array compiles in time linear in its length
+    # (~50 s at 2^19 rows, against ~2 s when padded first)
+    pad = padded_rows(seg.shape[0]) - seg.shape[0]
+    if pad:
+        seg = jnp.pad(seg, (0, pad), constant_values=num_segments)
+        valid = jnp.pad(valid, (0, pad))
+        values = jax.tree.map(
+            lambda a: jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1)),
+            values)
     leaves, treedef = jax.tree.flatten(values)
     counts = None
     outs = []
@@ -253,7 +265,7 @@ def _segment_reduce_pallas(kind, seg, values, valid, num_segments):
 
 
 # ---------------------------------------------------------------------------
-# shuffle_reduce: the fused shuffle+merge+Reduce hot path
+# shuffle_reduce: the shuffle+merge+Reduce hot path
 # ---------------------------------------------------------------------------
 
 class ShuffleReduced(NamedTuple):
@@ -269,22 +281,12 @@ class ShuffleReduced(NamedTuple):
 
 
 _INT32_MAX = 2**31 - 1
-_FUSED_MAX_D = 512       # value width cap for the fused kernel's VMEM tile
-_FUSED_MAX_KEYS = 4096   # affected-key cap (single one-hot block per tile)
-
-
-def _can_fuse(kind: str, leaves, n: int, key_cap: int) -> bool:
-    return (kind in ("sum", "mean") and len(leaves) == 1
-            and leaves[0].ndim <= 2 and n > 0
-            and 0 < key_cap <= _FUSED_MAX_KEYS
-            and (leaves[0].size // max(n, 1)) <= _FUSED_MAX_D)
 
 
 def shuffle_reduce(reducer, k2: jax.Array, mk: jax.Array, values: Any,
                    valid: jax.Array, sign: jax.Array,
                    affected_keys: jax.Array, *,
-                   backend: Optional[str] = None,
-                   fused: Optional[bool] = None) -> ShuffleReduced:
+                   backend: Optional[str] = None) -> ShuffleReduced:
     """Shuffle-sort, last-writer-wins merge, and reduce in one call.
 
     The engine's whole merge hot path: rows are sorted stably by (k2, mk)
@@ -292,35 +294,11 @@ def shuffle_reduce(reducer, k2: jax.Array, mk: jax.Array, values: Any,
     survives if its sign is positive (tombstones delete), and the live
     rows' values are reduced into the slots of ``affected_keys`` (sorted
     ascending, unique, padded with int32 max; ``counts`` counts live rows
-    per slot, mean division stays with ``finalize_reduce``).
-
-    ``fused=None`` picks the fused Pallas kernel automatically when the
-    backend is pallas and the monoid supports it (sum/mean, single
-    modest-width value leaf); ``False`` forces the composed path;
-    ``True`` requires fusion and raises where unsupported.  Both paths
-    implement the identical contract — the composed path on xla is the
-    bitwise reference.
+    per slot, mean division stays with ``finalize_reduce``).  Both
+    backends run the same composition (:func:`sort_pairs`, then
+    :func:`segment_reduce`), so the xla path is the bitwise reference.
     """
     bk = resolve_backend(backend)
-    kind = _kind_of(reducer)
-    n = k2.shape[0]
-    key_cap = affected_keys.shape[0]
-    leaves, treedef = jax.tree.flatten(values)
-    fusable = bk == "pallas" and _can_fuse(kind, leaves, n, key_cap)
-    if fused and not fusable:
-        raise ValueError(
-            "fused shuffle_reduce requires the pallas backend, a sum/mean "
-            "reducer, and a single value leaf of width <= "
-            f"{_FUSED_MAX_D} with 0 < key_cap <= {_FUSED_MAX_KEYS}")
-    if fusable and fused is not False:
-        return _shuffle_reduce_fused(kind, k2, mk, leaves[0], treedef,
-                                     valid, sign, affected_keys)
-    return _shuffle_reduce_composed(reducer, kind, bk, k2, mk, values,
-                                    valid, sign, affected_keys)
-
-
-def _shuffle_reduce_composed(reducer, kind, bk, k2, mk, values, valid, sign,
-                             affected_keys) -> ShuffleReduced:
     n = k2.shape[0]
     key_cap = affected_keys.shape[0]
     k2m = jnp.where(valid, k2, jnp.int32(_INT32_MAX))
@@ -342,24 +320,6 @@ def _shuffle_reduce_composed(reducer, kind, bk, k2, mk, values, valid, sign,
     acc, counts = segment_reduce(reducer, local, vals_s, live & in_set,
                                  key_cap, backend=bk)
     return ShuffleReduced(res.k2, res.mk, vals_s, live, res.perm, acc,
-                          counts)
-
-
-def _shuffle_reduce_fused(kind, k2, mk, leaf, treedef, valid, sign,
-                          affected_keys) -> ShuffleReduced:
-    from repro.kernels.fused import fused_shuffle_reduce
-    key_cap = affected_keys.shape[0]
-    out_dtype = (jnp.int32 if jnp.issubdtype(leaf.dtype, jnp.integer)
-                 else jnp.float32)
-    k2m = jnp.where(valid, k2, jnp.int32(_INT32_MAX))
-    flat = leaf.reshape(leaf.shape[0], -1)
-    k2s, mks, vals_s, live, perm, acc, counts = fused_shuffle_reduce(
-        k2m, mk, flat, valid, sign, affected_keys, out_dtype=out_dtype,
-        interpret=_interpret())
-    vals_s = vals_s.reshape(leaf.shape)
-    acc = acc.astype(leaf.dtype).reshape((key_cap,) + leaf.shape[1:])
-    return ShuffleReduced(k2s, mks, jax.tree.unflatten(treedef, [vals_s]),
-                          live, perm, jax.tree.unflatten(treedef, [acc]),
                           counts)
 
 

@@ -6,26 +6,27 @@ matrix units.  For a tile of R rows with segment ids ``seg[R]`` and values
 
     onehot(seg)[R, K]^T @ vals[R, D]     (one 128x128-aligned MXU matmul)
 
-The grid walks (row tiles x output blocks); each output block stays
-resident in VMEM across the row-tile loop (BlockSpec index_map pins it),
-accumulating partial sums — the classic stationary-output tiling.
+The grid walks (output blocks x row tiles) with the row tiles innermost;
+each output block stays resident in VMEM across its row-tile loop
+(BlockSpec index_map pins it), accumulating partial sums — the classic
+stationary-output tiling.  The order matters on the chip: Pallas writes
+an output block back when its index changes and never reads it back, so
+the reduction axis must be the innermost one.
 
 Three kernel families cover all four ``Reducer`` monoids:
 
   * ``segment_sum_mxu``        — sum and mean (mean = sum + count, the
     division happens in ``kvstore.finalize_reduce``); integer values
-    accumulate in int32, floats in float32.
+    accumulate exactly in int32 (byte limbs on the MXU, see
+    ``_onehot_dot``), floats in float32.
   * ``segment_sum_counts_mxu`` — the same matmul with the per-segment row
     counts as a second output of the *same* launch (counts are the one-hot
     column sums, already resident), so the dispatcher's (acc, counts)
     contract costs one kernel instead of two.
   * ``segment_minmax_mxu``     — min/max via a *sublane* reduction: rows
-    stream through in chunks of ``SUBLANES`` (the VPU's 8-row register
-    height), each chunk masked against the one-hot block and folded into a
-    stationary [kblk, D] accumulator.  Peak intermediate is
-    [SUBLANES, kblk, D] — the old masked-select kernel materialized the
-    full [rows, kblk, D] cube, which is why its tile knobs were clamped to
-    a quarter of the sum kernel's; they now share the same defaults.
+    stream through in aligned chunks of ``SUBLANES`` (the VPU's 8-row
+    register height), each row folded into the rows of a stationary
+    [kblk, D] accumulator that its segment id selects.
   * ``segment_reduce_mxu``     — the original float32 sum entry point,
     kept as the benchmark/back-compat surface.
 
@@ -43,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels import jitcache
 from repro.kernels.ref import segment_minmax_ref, segment_reduce_ref  # noqa: F401
 from repro.kernels.sort_u32 import default_interpret
 
@@ -66,30 +68,56 @@ def _block_live(seg, base: int, kblk: int):
     return jnp.any((seg >= base) & (seg < base + kblk))
 
 
+INT_ROWS_MAX = 1 << 16  # integer sums: a tile's byte-limb sums stay < 2**24
+
+
+def _onehot_dot(onehot, vals, out_dtype):
+    """``onehot[R, K]^T @ vals[R, D]`` on the MXU, in ``out_dtype``.
+
+    Floats run one matmul (float32 at full precision).  The MXU takes no
+    int32 operands, so int32 values are split into four unsigned byte
+    limbs, each an exact bf16; each limb's per-tile sum (at most
+    ``rows * 255 < 2**24``) is exact in the float32 accumulator, and the
+    limbs recombine with wrapping int32 shifts.  The result is the int32
+    sum modulo 2**32, bit for bit what ``jax.ops.segment_sum`` gives.
+    """
+    if not jnp.issubdtype(out_dtype, jnp.integer):
+        precision = (jax.lax.Precision.HIGHEST if vals.dtype == jnp.float32
+                     else None)
+        return jnp.dot(onehot.astype(vals.dtype).T, vals,
+                       preferred_element_type=out_dtype, precision=precision)
+    oh = onehot.astype(jnp.bfloat16).T
+    acc = None
+    for b in range(4):
+        limb = jnp.bitwise_and(jnp.right_shift(vals, 8 * b), 0xFF)
+        part = jnp.dot(oh, limb.astype(jnp.float32).astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+        part = jnp.left_shift(part.astype(jnp.int32), 8 * b)
+        acc = part if acc is None else acc + part
+    return acc
+
+
 def _sum_kernel(seg_ref, val_ref, out_ref, *, kblk: int, rows: int):
-    i = pl.program_id(0)      # row tile
+    i = pl.program_id(1)      # row tile (innermost: the reduction axis)
 
     @pl.when(i == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
     seg = seg_ref[...]                        # [rows]
-    base = pl.program_id(1) * kblk
+    base = pl.program_id(0) * kblk
 
     @pl.when(_block_live(seg, base, kblk))
     def _work():
-        vals = val_ref[...]                   # [rows, D]
         local = seg - base
         onehot = (local[:, None] ==
                   jax.lax.broadcasted_iota(jnp.int32, (rows, kblk), 1))
-        onehot = onehot.astype(vals.dtype)
-        out_ref[...] += jnp.dot(onehot.T, vals,
-                                preferred_element_type=out_ref.dtype)
+        out_ref[...] += _onehot_dot(onehot, val_ref[...], out_ref.dtype)
 
 
 def _sum_counts_kernel(seg_ref, val_ref, out_ref, cnt_ref, *, kblk: int,
                        rows: int):
-    i = pl.program_id(0)
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
@@ -97,45 +125,45 @@ def _sum_counts_kernel(seg_ref, val_ref, out_ref, cnt_ref, *, kblk: int,
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
     seg = seg_ref[...]
-    base = pl.program_id(1) * kblk
+    base = pl.program_id(0) * kblk
 
     @pl.when(_block_live(seg, base, kblk))
     def _work():
-        vals = val_ref[...]
         local = seg - base
         onehot = (local[:, None] ==
                   jax.lax.broadcasted_iota(jnp.int32, (rows, kblk), 1))
+        # int32 column sums on the VPU: exact past 2**24 rows per segment
         cnt_ref[...] += jnp.sum(onehot.astype(jnp.int32), axis=0)[:, None]
-        onehot = onehot.astype(vals.dtype)
-        out_ref[...] += jnp.dot(onehot.T, vals,
-                                preferred_element_type=out_ref.dtype)
+        out_ref[...] += _onehot_dot(onehot, val_ref[...], out_ref.dtype)
 
 
 def _minmax_kernel(seg_ref, val_ref, out_ref, *, kblk: int, rows: int,
                    is_min: bool, ident):
-    i = pl.program_id(0)
+    """Rows stream through in aligned chunks of ``SUBLANES``; each row of a
+    chunk folds into the accumulator rows of its segment.  ``seg`` is a
+    ``[rows, 1]`` column so a chunk is one aligned sublane slice."""
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
         out_ref[...] = jnp.full_like(out_ref, ident)
 
-    base = pl.program_id(1) * kblk
+    base = pl.program_id(0) * kblk
     d = val_ref.shape[1]
     dtype = val_ref.dtype
     fold = jnp.minimum if is_min else jnp.maximum
-    kiota = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, kblk), 1)
-    idval = jnp.asarray(ident, dtype)
+    kcol = base + jax.lax.broadcasted_iota(jnp.int32, (kblk, 1), 0)
 
     @pl.when(_block_live(seg_ref[...], base, kblk))
     def _work():
         def chunk(c, acc):
-            r0 = c * SUBLANES
-            seg8 = seg_ref[pl.ds(r0, SUBLANES)] - base    # [8]
+            r0 = pl.multiple_of(c * SUBLANES, SUBLANES)
+            seg8 = seg_ref[pl.ds(r0, SUBLANES), :]        # [8, 1]
             vals8 = val_ref[pl.ds(r0, SUBLANES), :]       # [8, D]
-            onehot = seg8[:, None] == kiota               # [8, kblk]
-            masked = jnp.where(onehot[:, :, None], vals8[:, None, :], idval)
-            red = masked.min(axis=0) if is_min else masked.max(axis=0)
-            return fold(acc, red)
+            for r in range(SUBLANES):
+                hit = kcol == seg8[r:r + 1, :]            # [kblk, 1]
+                acc = jnp.where(hit, fold(acc, vals8[r:r + 1, :]), acc)
+            return acc
 
         acc0 = jnp.full((kblk, d), ident, dtype)
         acc = jax.lax.fori_loop(0, rows // SUBLANES, chunk, acc0)
@@ -144,6 +172,12 @@ def _minmax_kernel(seg_ref, val_ref, out_ref, *, kblk: int, rows: int,
 
 def _round_up(n: int, mult: int) -> int:
     return ((n + mult - 1) // mult) * mult
+
+
+def padded_rows(n: int, rows: int = DEFAULT_ROWS) -> int:
+    """The least row count >= ``n`` that every kernel here tiles without
+    padding: a multiple of the row tile, or of ``SUBLANES`` below one."""
+    return _round_up(n, rows if n >= rows else SUBLANES)
 
 
 def _pad_rows(seg, vals, rows, num_segments, *, fill=0, multiple=1):
@@ -186,19 +220,22 @@ def segment_sum_mxu(seg: jax.Array, vals: jax.Array, num_segments: int, *,
         return jnp.zeros((max(num_segments, 0), d), out_dtype)
     if n == 0:
         return jnp.zeros((num_segments, d), out_dtype)
+    if jnp.issubdtype(out_dtype, jnp.integer):
+        rows = min(rows, INT_ROWS_MAX)
     seg, vals, rows = _pad_rows(seg, vals, rows, num_segments)
     n, d = vals.shape
     kblk, kfull = _kblocks(num_segments, kblk)
     if jnp.issubdtype(vals.dtype, jnp.integer):
         vals = vals.astype(out_dtype)
+    jitcache.count_trace("kernels.segment_sum")
     out = pl.pallas_call(
         functools.partial(_sum_kernel, kblk=kblk, rows=rows),
-        grid=(n // rows, kfull // kblk),
+        grid=(kfull // kblk, n // rows),
         in_specs=[
-            pl.BlockSpec((rows,), lambda i, j: (i,)),
-            pl.BlockSpec((rows, d), lambda i, j: (i, 0)),
+            pl.BlockSpec((rows,), lambda j, i: (i,)),
+            pl.BlockSpec((rows, d), lambda j, i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((kblk, d), lambda i, j: (j, 0)),
+        out_specs=pl.BlockSpec((kblk, d), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((kfull, d), out_dtype),
         interpret=interpret,
     )(seg.astype(jnp.int32), vals)
@@ -228,20 +265,23 @@ def segment_sum_counts_mxu(seg: jax.Array, vals: jax.Array,
     if n == 0:
         return (jnp.zeros((num_segments, d), out_dtype),
                 jnp.zeros(num_segments, jnp.int32))
+    if jnp.issubdtype(out_dtype, jnp.integer):
+        rows = min(rows, INT_ROWS_MAX)
     seg, vals, rows = _pad_rows(seg, vals, rows, num_segments)
     n, d = vals.shape
     kblk, kfull = _kblocks(num_segments, kblk)
     if jnp.issubdtype(vals.dtype, jnp.integer):
         vals = vals.astype(out_dtype)
+    jitcache.count_trace("kernels.segment_sum_counts")
     out, cnt = pl.pallas_call(
         functools.partial(_sum_counts_kernel, kblk=kblk, rows=rows),
-        grid=(n // rows, kfull // kblk),
+        grid=(kfull // kblk, n // rows),
         in_specs=[
-            pl.BlockSpec((rows,), lambda i, j: (i,)),
-            pl.BlockSpec((rows, d), lambda i, j: (i, 0)),
+            pl.BlockSpec((rows,), lambda j, i: (i,)),
+            pl.BlockSpec((rows, d), lambda j, i: (i, 0)),
         ],
-        out_specs=[pl.BlockSpec((kblk, d), lambda i, j: (j, 0)),
-                   pl.BlockSpec((kblk, 1), lambda i, j: (j, 0))],
+        out_specs=[pl.BlockSpec((kblk, d), lambda j, i: (j, 0)),
+                   pl.BlockSpec((kblk, 1), lambda j, i: (j, 0))],
         out_shape=[jax.ShapeDtypeStruct((kfull, d), out_dtype),
                    jax.ShapeDtypeStruct((kfull, 1), jnp.int32)],
         interpret=interpret,
@@ -277,18 +317,19 @@ def segment_minmax_mxu(kind: str, seg: jax.Array, vals: jax.Array,
                                 multiple=SUBLANES)
     n, d = vals.shape
     kblk, kfull = _kblocks(num_segments, kblk)
+    jitcache.count_trace("kernels.segment_minmax")
     out = pl.pallas_call(
         functools.partial(_minmax_kernel, kblk=kblk, rows=rows,
                           is_min=(kind == "min"), ident=ident),
-        grid=(n // rows, kfull // kblk),
+        grid=(kfull // kblk, n // rows),
         in_specs=[
-            pl.BlockSpec((rows,), lambda i, j: (i,)),
-            pl.BlockSpec((rows, d), lambda i, j: (i, 0)),
+            pl.BlockSpec((rows, 1), lambda j, i: (i, 0)),
+            pl.BlockSpec((rows, d), lambda j, i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((kblk, d), lambda i, j: (j, 0)),
+        out_specs=pl.BlockSpec((kblk, d), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((kfull, d), vals.dtype),
         interpret=interpret,
-    )(seg.astype(jnp.int32), vals)
+    )(seg.astype(jnp.int32)[:, None], vals)
     return out[:num_segments]
 
 

@@ -4,7 +4,9 @@ Hadoop's shuffle sorts spill files with comparison mergesort on the CPU;
 the TPU analogue is a data-parallel bitonic network over VMEM-resident
 tiles: log²(T) compare-exchange stages, each a vectorized select between a
 tile and its stride-permuted self (no data-dependent control flow, VPU
-friendly).
+friendly).  Each lane is held as a ``(rows, 128)`` tile in row-major
+order, so a stage's partner is a lane rotate (distance < 128) or a
+sublane rotate (distance >= 128) — both native on the VPU.
 
 The network sorts three int lanes lexicographically: a primary key, a
 secondary key, and the original row index.  Because the index lane is
@@ -27,9 +29,9 @@ bitonic network at tile granularity (``SORT_TILE`` rows per tile):
     pairs, two tiles resident in VMEM per step.
 
 Total work stays the bitonic O(n log² n) while VMEM is bounded by the
-tile size — the old pad-the-whole-input-to-one-power-of-two block (and
-its fall-off-a-cliff behavior past a few thousand rows) is gone.  Inputs
-that do fit one tile take the exact single-launch path they always did.
+tile size.  Inputs that fit one tile run the whole network in one
+launch; every input is padded to at least ``MIN_TILE`` rows, one
+(8, 128) int32 vreg.
 
 ``interpret`` defaults to auto-detection (interpret off TPU, native on
 TPU); set ``REPRO_PALLAS_INTERPRET=0/1`` to override.  ``repro.kernels.
@@ -42,12 +44,15 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import jitcache
 from repro.kernels.ref import sort_kv32_ref  # noqa: F401  (back-compat)
 
 SORT_TILE = 4096        # rows per VMEM tile (power of two)
+LANES = 128             # lane width of a vreg: tiles are (rows, LANES)
+MIN_TILE = 8 * LANES    # one (8, 128) int32 vreg: the smallest tile
 
 
 def default_interpret() -> bool:
@@ -69,28 +74,56 @@ def default_interpret() -> bool:
 
 
 def _lex_lt(ah, al, ai, bh, bl, bi):
-    """(ah, al, ai) < (bh, bl, bi) lexicographically."""
-    return jnp.where(ah != bh, ah < bh, jnp.where(al != bl, al < bl, ai < bi))
+    """(ah, al, ai) < (bh, bl, bi) lexicographically.
+
+    Pure boolean algebra: Mosaic has no select between boolean vectors.
+    """
+    return (ah < bh) | ((ah == bh) & ((al < bl) | ((al == bl) & (ai < bi))))
+
+
+def _partner(x, j: int, coord, axis: int, size: int):
+    """``x`` at tile position ``p XOR j`` along ``axis`` (lanes or rows).
+
+    Two cyclic rolls bring both candidates (``p + j`` and ``p - j``) to
+    ``p``; rolling the coordinate iota the same way picks the one that is
+    the partner, so the result does not depend on the roll's direction
+    convention.  Mosaic lowers both rolls natively (lane and sublane
+    rotates), which a data-dependent gather does not.
+    """
+    fwd = pltpu.roll(x, j, axis)
+    if 2 * j == size:                 # both rolls coincide
+        return fwd
+    bwd = pltpu.roll(x, size - j, axis)
+    src = pltpu.roll(coord, j, axis)
+    return jnp.where(src == jnp.bitwise_xor(coord, j), fwd, bwd)
 
 
 def _stage(hi, lo, idx, j, k, base):
     """One intra-tile compare-exchange stage of the *global* network.
 
-    ``base`` is the tile's global row offset: directions are a function of
-    global position, which is what lets independently launched tiles each
-    compute their slice of one coherent bitonic network.
+    Lanes are ``(rows, 128)`` tiles in row-major order (position
+    ``row * 128 + lane``).  A partner at distance ``j < 128`` sits in the
+    same row; one at ``j >= 128`` sits ``j / 128`` rows away in the same
+    lane.  ``base`` is the tile's global row offset: directions are a
+    function of global position, which is what lets independently
+    launched tiles each compute their slice of one coherent network.
     """
-    n = hi.shape[0]
-    pos = jax.lax.iota(jnp.int32, n)
-    partner = jnp.bitwise_xor(pos, j)
-    ph = hi[partner]
-    plo = lo[partner]
-    pi = idx[partner]
+    rows = hi.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, hi.shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, hi.shape, 1)
+    pos = row * LANES + lane
+    if j < LANES:
+        take = functools.partial(_partner, j=j, coord=lane, axis=1,
+                                 size=LANES)
+    else:
+        take = functools.partial(_partner, j=j // LANES, coord=row, axis=0,
+                                 size=rows)
+    ph, plo, pi = take(hi), take(lo), take(idx)
     up = (jnp.bitwise_and(base + pos, k) == 0)   # ascending region?
-    is_lo = pos < partner
+    is_lo = jnp.bitwise_and(pos, j) == 0
     want_min = up == is_lo
     own_lt = _lex_lt(hi, lo, idx, ph, plo, pi)   # never equal: idx is unique
-    take_own = jnp.where(want_min, own_lt, ~own_lt)
+    take_own = want_min == own_lt
     sel = lambda a, b: jnp.where(take_own, a, b)
     return sel(hi, ph), sel(lo, plo), sel(idx, pi)
 
@@ -147,7 +180,7 @@ def _cross_kernel(ahi_ref, alo_ref, ai_ref, bhi_ref, blo_ref, bi_ref,
     ah, al, ai = ahi_ref[...], alo_ref[...], ai_ref[...]
     bh, bl, bi = bhi_ref[...], blo_ref[...], bi_ref[...]
     a_lt = _lex_lt(ah, al, ai, bh, bl, bi)         # never equal
-    take_a = jnp.where(up, a_lt, ~a_lt)            # lower position keeps min
+    take_a = up == a_lt                            # lower position keeps min
     want_a = take_a == (side == 0)                 # upper side keeps the rest
     oh_ref[...] = jnp.where(want_a, ah, bh)
     ol_ref[...] = jnp.where(want_a, al, bl)
@@ -155,34 +188,43 @@ def _cross_kernel(ahi_ref, alo_ref, ai_ref, bhi_ref, blo_ref, bi_ref,
 
 
 def _lane_specs(tile: int, index_map):
-    return [pl.BlockSpec((tile,), index_map) for _ in range(3)]
+    return [pl.BlockSpec((tile // LANES, LANES), index_map)
+            for _ in range(3)]
 
 
 def _lane_shapes(m: int, hi_dtype, lo_dtype):
-    return [jax.ShapeDtypeStruct((m,), hi_dtype),
-            jax.ShapeDtypeStruct((m,), lo_dtype),
-            jax.ShapeDtypeStruct((m,), jnp.int32)]
+    shape = (m // LANES, LANES)
+    return [jax.ShapeDtypeStruct(shape, hi_dtype),
+            jax.ShapeDtypeStruct(shape, lo_dtype),
+            jax.ShapeDtypeStruct(shape, jnp.int32)]
 
 
-def sorted_lanes(hi: jax.Array, lo: jax.Array, idx: jax.Array, *,
-                 tile: int, interpret: bool):
-    """Sort pre-padded (hi, lo, idx) lanes; length must be pow2·tile or a
-    pow2 below one tile.  The building block shared with ``kernels.fused``.
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def sorted_lanes(hi: jax.Array, lo: jax.Array, *, tile: int,
+                 interpret: bool):
+    """Sort pre-padded (hi, lo) lanes of length ``m``, a power of two of at
+    least ``MIN_TILE``, with the row index as the third lane; lengths past
+    ``tile`` run the multi-tile network.  Returns ``(hi, lo, perm)`` flat;
+    the kernels see each lane as ``(m / 128, 128)`` tiles.
     """
+    jitcache.count_trace("kernels.sort_lex")
     m = hi.shape[0]
+    idx = jnp.arange(m, dtype=jnp.int32)
+    hi, lo, idx = (a.reshape(m // LANES, LANES) for a in (hi, lo, idx))
     if m <= tile:
-        # single tile: the whole network in one launch (the original path)
-        return pl.pallas_call(
+        # single tile: the whole network in one launch
+        hi, lo, idx = pl.pallas_call(
             functools.partial(_tile_sort_kernel, tile=m),
             grid=(1,),
-            in_specs=_lane_specs(m, lambda i: (0,)),
-            out_specs=_lane_specs(m, lambda i: (0,)),
+            in_specs=_lane_specs(m, lambda i: (0, 0)),
+            out_specs=_lane_specs(m, lambda i: (0, 0)),
             out_shape=_lane_shapes(m, hi.dtype, lo.dtype),
             interpret=interpret,
         )(hi, lo, idx)
+        return hi.reshape(m), lo.reshape(m), idx.reshape(m)
 
     tiles = m // tile
-    per_tile = lambda i: (i,)
+    per_tile = lambda i: (i, 0)
     hi, lo, idx = pl.pallas_call(
         functools.partial(_tile_sort_kernel, tile=tile),
         grid=(tiles,),
@@ -197,11 +239,11 @@ def sorted_lanes(hi: jax.Array, lo: jax.Array, idx: jax.Array, *,
         j = k // 2
         while j >= tile:
             dt = j // tile
-            lo_map = lambda p, s, dt=dt: ((p // dt) * (2 * dt) + (p % dt),)
+            lo_map = lambda p, s, dt=dt: ((p // dt) * (2 * dt) + (p % dt), 0)
             hi_map = lambda p, s, dt=dt: (
-                (p // dt) * (2 * dt) + (p % dt) + dt,)
+                (p // dt) * (2 * dt) + (p % dt) + dt, 0)
             out_map = lambda p, s, dt=dt: (
-                (p // dt) * (2 * dt) + (p % dt) + s * dt,)
+                (p // dt) * (2 * dt) + (p % dt) + s * dt, 0)
             hi, lo, idx = pl.pallas_call(
                 functools.partial(_cross_kernel, tile=tile, k=k, dt=dt),
                 grid=(tiles // 2, 2),
@@ -220,18 +262,19 @@ def sorted_lanes(hi: jax.Array, lo: jax.Array, idx: jax.Array, *,
             interpret=interpret,
         )(hi, lo, idx)
         k *= 2
-    return hi, lo, idx
+    return hi.reshape(m), lo.reshape(m), idx.reshape(m)
 
 
 def _type_max(dtype):
     return jnp.iinfo(dtype).max
 
 
-def padded_length(n: int, tile: int) -> int:
-    """Pad policy: next power of two up to one tile, then tile multiples
-    whose count is a power of two (the bitonic network needs pow2 total)."""
-    m = 1
-    while m < max(n, 1):
+def padded_length(n: int) -> int:
+    """Pad policy: the next power of two, and at least one ``MIN_TILE``
+    (the bitonic network needs a power-of-two total; past one tile that
+    is a power-of-two count of tiles)."""
+    m = MIN_TILE
+    while m < n:
         m *= 2
     return m
 
@@ -246,29 +289,27 @@ def pad_lanes(hi: jax.Array, lo: jax.Array, m: int):
     return hi, lo
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def sort_lex_pallas(hi: jax.Array, lo: jax.Array, *, tile: int = SORT_TILE,
                     interpret: bool | None = None):
     """Stable lexicographic sort by (hi, lo); ties broken by row index.
 
     Returns ``(hi_sorted, lo_sorted, perm)`` where ``perm`` is the int32
-    permutation (``hi_sorted == hi[perm]``).  Length is padded to the next
-    power of two with both key lanes at their dtype max, so padding lands
-    at the tail and ``perm[:n]`` is a permutation of ``range(n)``.  Inputs
-    beyond ``tile`` rows run the multi-tile network: VMEM stays bounded by
-    the tile size (two tiles per cross-stage launch) instead of the whole
-    padded input.
+    permutation (``hi_sorted == hi[perm]``).  Length is padded to
+    :func:`padded_length` with both key lanes at their dtype max, so
+    padding lands at the tail and ``perm[:n]`` is a permutation of
+    ``range(n)``.  Inputs beyond ``tile`` rows run the multi-tile network:
+    VMEM stays bounded by the tile size (two tiles per cross-stage launch)
+    instead of the whole padded input.  The network is compiled once per
+    padded length, not once per input length.
     """
     if interpret is None:
         interpret = default_interpret()
-    if tile & (tile - 1):
-        raise ValueError(f"tile must be a power of two, got {tile}")
+    if tile & (tile - 1) or tile < MIN_TILE:
+        raise ValueError(
+            f"tile must be a power of two >= {MIN_TILE}, got {tile}")
     n = hi.shape[0]
-    m = padded_length(n, tile)
-    hi, lo = pad_lanes(hi, lo, m)
-    iota = jnp.arange(m, dtype=jnp.int32)
-    ho, lo_out, perm = sorted_lanes(hi, lo, iota, tile=tile,
-                                    interpret=interpret)
+    hi, lo = pad_lanes(hi, lo, padded_length(n))
+    ho, lo_out, perm = sorted_lanes(hi, lo, tile=tile, interpret=interpret)
     return ho[:n], lo_out[:n], perm[:n]
 
 

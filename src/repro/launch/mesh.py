@@ -9,10 +9,29 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-# TPU v5e hardware constants used across the roofline analysis
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link (~uni-directional)
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s inter-chip interconnect.
+# The ICI figure below is the roofline's per-link uni-directional model.
+V5E = "TPU v5 lite"
+PEAKS = {
+    V5E: {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The published peaks of ``device_kind``; an unknown kind raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
+
+# the roofline analysis models the v5e production pod
+PEAK_FLOPS = peaks(V5E)["flops_bf16"]
+HBM_BW = peaks(V5E)["hbm_bw"]
+ICI_BW = peaks(V5E)["ici_bw"]
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

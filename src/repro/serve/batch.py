@@ -13,7 +13,7 @@ and drives the union through a *single* pass of the existing engine:
    serves everyone;
 3. one bucketed :func:`~repro.core.incremental._combine_edges` +
    :func:`~repro.core.incremental._merge_reduce` launch (the same
-   ``ops.shuffle_reduce`` path — fused on the pallas backend — and the
+   ``ops.shuffle_reduce`` path and the
    same power-of-two bucket ladder, so executables are shared with the
    solo path's cache discipline);
 4. a host-side split of the merged chunks and reduced values back to each

@@ -153,3 +153,74 @@ class TestAPriori:
         job.incremental_run(delta)
         want = apriori.oracle(np.concatenate([tweets, new]), pairs)
         np.testing.assert_allclose(job.view.as_dict()["c"], want)
+
+
+# ---------------------------------------------------------------------------
+# the vectorized NumPy oracles vs plain per-record loops of the same semantics
+# ---------------------------------------------------------------------------
+
+def _wordcount_loop(docs, vocab, valid):
+    counts = np.zeros(vocab)
+    for i, d in enumerate(docs):
+        if valid[i]:
+            for w in d:
+                if w >= 0:
+                    counts[w] += 1
+    return counts
+
+
+def _pagerank_loop(nbrs, valid, damping):
+    s = nbrs.shape[0]
+    r = np.ones(s)
+    for _ in range(200):
+        acc = np.zeros(s)
+        for i in range(s):
+            out = nbrs[i][nbrs[i] >= 0]
+            if valid[i] and out.size:
+                np.add.at(acc, out, r[i] / out.size)
+        new = damping * acc + (1 - damping)
+        done = np.abs(new - r).max() < 1e-12
+        r = new
+        if done:
+            break
+    return r
+
+
+def _sssp_loop(nbrs, w, src, valid, inf):
+    """Bellman-Ford relaxing in place, edge by edge."""
+    s = nbrs.shape[0]
+    d = np.full(s, np.float64(inf))
+    d[src] = 0.0
+    changed = True
+    while changed:
+        changed = False
+        for i in range(s):
+            if not valid[i] or d[i] >= inf / 2:
+                continue
+            for jj, jv in enumerate(nbrs[i]):
+                if jv >= 0 and d[i] + w[i, jj] < d[jv] - 1e-12:
+                    d[jv] = d[i] + w[i, jj]
+                    changed = True
+    return d
+
+
+@pytest.mark.parametrize("app", ["wordcount", "pagerank", "sssp"])
+def test_oracle_matches_loop_reference(app):
+    from repro.apps import pagerank as pr, sssp, wordcount as wc
+    rng = np.random.default_rng(4)
+    n = 120
+    valid = rng.random(n) < 0.9
+    if app == "wordcount":
+        docs = rng.integers(-1, 30, (n, 6)).astype(np.int32)
+        np.testing.assert_array_equal(wc.oracle(docs, 30, valid),
+                                      _wordcount_loop(docs, 30, valid))
+    elif app == "pagerank":
+        nbrs = pr.random_graph(n, 5, seed=3)
+        np.testing.assert_allclose(pr.oracle(nbrs, valid),
+                                   _pagerank_loop(nbrs, valid, pr.DAMPING),
+                                   rtol=1e-12)
+    else:
+        nbrs, w = sssp.random_weighted_graph(n, 4, seed=3, p_edge=0.4)
+        np.testing.assert_allclose(sssp.oracle(nbrs, w, 0, valid),
+                                   _sssp_loop(nbrs, w, 0, valid, sssp.INF),
+                                   rtol=1e-12)

@@ -77,6 +77,19 @@ def test_sort_edges_parity_with_invalid_rows(n, seed):
     assert (k2[~valid] == int(INVALID_KEY)).all()
 
 
+@pytest.mark.parametrize("n", [1023, 1025, 4095, 4097, 9000])
+def test_sort_pairs_parity_tile_straddling(n):
+    """Sizes on both sides of the one-vreg minimum and of SORT_TILE (the
+    multi-tile network), with heavy ties on both keys."""
+    rng = np.random.default_rng(n)
+    k2 = jnp.asarray(rng.integers(0, max(n // 64, 2), n), jnp.int32)
+    mk = jnp.asarray(rng.integers(0, 3, n), jnp.int32)
+    rx, rp = _both(lambda bk: ops.sort_pairs(k2, mk, None, backend=bk))
+    for name in ("perm", "k2", "mk"):
+        np.testing.assert_array_equal(np.asarray(getattr(rx, name)),
+                                      np.asarray(getattr(rp, name)))
+
+
 def test_sort_pairs_single_key_stable():
     rng = np.random.default_rng(0)
     n = 129                                     # non-power-of-two
@@ -109,6 +122,10 @@ def test_segment_reduce_parity(kind, n, seed):
         "f": jnp.asarray(rng.integers(-8, 9, n).astype(np.float32)),
         "m": jnp.asarray(rng.integers(-8, 9, (n, 3)).astype(np.float32)),
         "i": jnp.asarray(rng.integers(-100, 100, n), jnp.int32),
+        # int32 near both limits: sums wrap modulo 2**32 on both backends
+        "w": jnp.asarray(np.where(rng.random(n) < 0.5, -1, 1)
+                         * rng.integers(2**31 - 300, 2**31 - 1, n),
+                         jnp.int32),
         # 3-D leaf: the pallas path flattens trailing dims
         "t": jnp.asarray(rng.integers(0, 5, (n, 2, 2)).astype(np.float32)),
     }

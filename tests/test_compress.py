@@ -82,6 +82,7 @@ print("OK")
 """
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"   # forced host devices; never a chip
     env["PYTHONPATH"] = SRC
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, env=env, timeout=600)

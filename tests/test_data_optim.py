@@ -73,7 +73,9 @@ class TestAdamW:
     def test_schedule_bounded(self, step):
         cfg = AdamWConfig(lr=3e-4, warmup=100, total_steps=10000)
         lr = float(cosine_schedule(cfg, jnp.int32(step)))
-        assert 0.0 <= lr <= cfg.lr + 1e-12
+        # the schedule runs in float32, where the peak itself rounds up
+        # (float32(3e-4) > 3e-4): at step == warmup lr is exactly that peak
+        assert 0.0 <= lr <= float(jnp.float32(cfg.lr))
 
     def test_global_norm(self):
         t = {"a": jnp.asarray([3.0]), "b": jnp.asarray([4.0])}

@@ -7,11 +7,13 @@ import pytest
 
 from repro.kernels.ref import sort_lex_ref
 from repro.kernels.segment_reduce import (
-    segment_minmax_mxu, segment_minmax_ref, segment_reduce_mxu,
+    INT_ROWS_MAX, segment_minmax_mxu, segment_minmax_ref, segment_reduce_mxu,
     segment_reduce_ref, segment_sum_counts_mxu, segment_sum_mxu,
 )
 from repro.kernels.flash_attention import flash_attention, mha_ref
-from repro.kernels.sort_u32 import sort_kv32, sort_kv32_ref, sort_lex_pallas
+from repro.kernels.sort_u32 import (
+    MIN_TILE, sort_kv32, sort_kv32_ref, sort_lex_pallas,
+)
 from repro.kernels.spmv_ell import spmv_ell, spmv_ell_ref
 
 
@@ -28,6 +30,44 @@ class TestSegmentReduce:
         tol = 1e-4 if dtype == jnp.float32 else 5e-2
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=tol, atol=tol)
+
+
+class TestSegmentSumExactInt:
+    """int32 sums via byte limbs on the MXU: bitwise equal to
+    ``jax.ops.segment_sum`` (wrapping modulo 2**32)."""
+
+    @pytest.mark.parametrize("n,d,k", [(7, 1, 3), (1023, 2, 40),
+                                       (1024, 3, 300), (1025, 1, 5),
+                                       (2049, 2, 700)])
+    @pytest.mark.parametrize("counts", [False, True])
+    def test_near_int32_limits(self, n, d, k, counts):
+        rng = np.random.default_rng(n * 7 + d)
+        seg = jnp.asarray(rng.integers(0, k + 2, n), jnp.int32)
+        mag = rng.integers(2**31 - 1000, 2**31, (n, d))
+        vals = np.where(rng.random((n, d)) < 0.5, -mag, mag - 1)
+        vals = jnp.asarray(vals.astype(np.int32))
+        want = jax.ops.segment_sum(vals, seg, num_segments=k + 2)[:k]
+        if counts:
+            got, cnt = segment_sum_counts_mxu(seg, vals, k,
+                                              out_dtype=jnp.int32)
+            np.testing.assert_array_equal(
+                np.asarray(cnt),
+                np.bincount(np.asarray(seg), minlength=k + 2)[:k])
+        else:
+            got = segment_sum_mxu(seg, vals, k, out_dtype=jnp.int32)
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_segment_past_2_24_rows(self):
+        """Counts and sums stay exact where float32 would round."""
+        n = (1 << 24) + 3
+        seg = jnp.zeros(n, jnp.int32).at[-1].set(1)
+        vals = jnp.full((n, 1), 3, jnp.int32)
+        acc, cnt = segment_sum_counts_mxu(seg, vals, 2, out_dtype=jnp.int32,
+                                          rows=INT_ROWS_MAX)
+        np.testing.assert_array_equal(np.asarray(cnt), [n - 1, 1])
+        np.testing.assert_array_equal(np.asarray(acc)[:, 0],
+                                      [3 * (n - 1), 3])
 
 
 class TestFlashAttention:
@@ -75,15 +115,17 @@ class TestSort:
 class TestSortMultiTile:
     """The cross-tile bitonic merge: sizes straddling every tile boundary.
 
-    ``tile=64`` keeps the multi-tile machinery cheap in interpret mode
-    while exercising the same code path the default SORT_TILE takes for
-    inputs past one VMEM tile.
+    ``tile=MIN_TILE`` (one (8, 128) vreg) is the smallest tile the native
+    lowering accepts; it keeps the multi-tile machinery cheap in interpret
+    mode while exercising the same code path the default SORT_TILE takes
+    for inputs past one VMEM tile.
     """
 
-    TILE = 64
+    TILE = MIN_TILE
 
     @pytest.mark.parametrize("n", [0, 1, 63, 64, 65, 127, 128, 129,
-                                   200, 256, 515, 1024])
+                                   200, 256, 515, 1024, 1025, 2047, 2049,
+                                   3000, 4097])
     def test_boundary_sweep(self, n):
         rng = np.random.default_rng(n + 17)
         hi = jnp.asarray(rng.integers(0, max(n // 2, 2), n), jnp.int32)
@@ -103,6 +145,37 @@ class TestSortMultiTile:
         _, _, perm = sort_lex_pallas(hi, lo, tile=self.TILE)
         np.testing.assert_array_equal(np.asarray(perm), np.arange(n))
 
+    @pytest.mark.parametrize("n", [1000, 3 * MIN_TILE + 7])
+    def test_ties_keep_input_order(self, n):
+        """Few distinct keys, all secondary keys equal: every run of ties
+        must come out in input order, within and across tiles."""
+        rng = np.random.default_rng(n)
+        hi = jnp.asarray(rng.integers(0, 3, n), jnp.int32)
+        lo = jnp.full(n, 4, jnp.int32)
+        gh, _, gp = sort_lex_pallas(hi, lo, tile=self.TILE)
+        gp, gh = np.asarray(gp), np.asarray(gh)
+        for key in range(3):
+            assert (np.diff(gp[gh == key]) > 0).all()
+        np.testing.assert_array_equal(gp, np.asarray(sort_lex_ref(hi, lo)[2]))
+
+    def test_extreme_keys(self):
+        """Keys at the int32 extremes, including the padding value itself:
+        real rows equal to the pad key still sort ahead of the padding."""
+        rng = np.random.default_rng(11)
+        n = MIN_TILE + 300
+        pool = np.array([-2**31, -2**31 + 1, -1, 0, 1, 2**31 - 2, 2**31 - 1],
+                        np.int32)
+        hi = jnp.asarray(rng.choice(pool, n))
+        lo = jnp.asarray(rng.choice(pool, n))
+        got = sort_lex_pallas(hi, lo, tile=self.TILE)
+        for a, b in zip(got, sort_lex_ref(hi, lo)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_tile_below_one_vreg_rejected(self):
+        hi = jnp.zeros(8, jnp.int32)
+        with pytest.raises(ValueError):
+            sort_lex_pallas(hi, hi, tile=MIN_TILE // 2)
+
     def test_vmem_bounded_padding(self):
         # a few tiles + 1 row must pad to the next tile multiple of the
         # network, not to the next power of two of a single giant tile
@@ -115,11 +188,11 @@ class TestSortMultiTile:
         assert sorted(np.asarray(gp).tolist()) == list(range(n))
 
     def test_matches_default_tile(self):
-        n = 300
+        n = 3000
         rng = np.random.default_rng(3)
         hi = jnp.asarray(rng.integers(0, 40, n), jnp.int32)
         lo = jnp.asarray(rng.integers(0, 5, n), jnp.int32)
-        small = sort_lex_pallas(hi, lo, tile=self.TILE)
+        small = sort_lex_pallas(hi, lo, tile=self.TILE)   # multi-tile
         big = sort_lex_pallas(hi, lo)          # single-tile path
         for a, b in zip(small, big):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -195,12 +268,12 @@ class TestSegmentMinMaxSublane:
             np.asarray(cnt), np.bincount(np.asarray(seg), minlength=k)[:k])
 
 
-class TestFusedShuffleReduce:
-    """kernels.fused vs the composed path: bitwise on integer-valued data.
+class TestShuffleReduce:
+    """ops.shuffle_reduce, pallas vs xla: bitwise on integer-valued data.
 
-    The composed xla path is the reference; the fused kernel must agree on
-    every output (sorted lanes, permutation, live mask, accumulators,
-    counts) at sizes straddling the fused tile boundary.
+    The xla path is the reference; the pallas path must agree on every
+    output (sorted lanes, permutation, live mask, accumulators, counts)
+    at sizes straddling the sort tile boundary.
     """
 
     @staticmethod
@@ -222,8 +295,8 @@ class TestFusedShuffleReduce:
     class _Sum:
         kind = "sum"
 
-    @pytest.mark.parametrize("n", [5, 100, 513, 1000])
-    def test_fused_vs_xla_bitwise(self, n):
+    @pytest.mark.parametrize("n", [5, 100, 513, 1000, 4097])
+    def test_pallas_vs_xla_bitwise(self, n):
         from repro.kernels import ops
         args = self._case(n, max(n // 4, 2), 3, n)
         ref = ops.shuffle_reduce(self._Sum(), *args, backend="xla")
@@ -237,43 +310,22 @@ class TestFusedShuffleReduce:
         np.testing.assert_array_equal(np.asarray(got.values),
                                       np.asarray(ref.values))
 
-    @pytest.mark.parametrize("n", [255, 256, 257, 515, 1024])
-    def test_multitile_fused(self, n):
-        """Small fused tile: the multi-tile sort + fused LWW/reduce pass."""
-        from repro.kernels import ops
-        from repro.kernels.fused import fused_shuffle_reduce
-        k2, mk, vals, valid, sign, keys = self._case(n, max(n // 3, 2), 2,
-                                                     n + 99)
-        k2m = jnp.where(valid, k2, jnp.int32(2**31 - 1))
-        out = fused_shuffle_reduce(k2m, mk, vals, valid, sign, keys,
-                                   out_dtype=jnp.float32, tile=128, kblk=64)
-        ref = ops.shuffle_reduce(self._Sum(), k2, mk, vals, valid, sign,
-                                 keys, backend="xla")
-        np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(ref.k2))
-        np.testing.assert_array_equal(np.asarray(out[3]),
-                                      np.asarray(ref.live))
-        np.testing.assert_array_equal(np.asarray(out[4]),
-                                      np.asarray(ref.perm))
-        np.testing.assert_array_equal(np.asarray(out[5]), np.asarray(ref.acc))
-        np.testing.assert_array_equal(np.asarray(out[6]),
-                                      np.asarray(ref.counts))
-
     def test_stability_witness(self):
         """Duplicate (k2, mk) rows: the *last* writer must win through the
-        multi-tile fused path (the engine's tombstone semantics)."""
-        from repro.kernels.fused import fused_shuffle_reduce
-        n, reps = 384, 3
+        multi-tile sort (the engine's tombstone semantics)."""
+        from repro.kernels import ops
+        n, reps = 3 * 1400, 3                   # past one SORT_TILE
         k2 = jnp.asarray(np.repeat(np.arange(n // reps, dtype=np.int32),
                                    reps))
         mk = jnp.zeros(n, jnp.int32)
         vals = jnp.asarray(np.arange(n, dtype=np.float32)[:, None])
         valid = jnp.ones(n, bool)
         sign = jnp.ones(n, np.int8)
-        keys = jnp.asarray(np.arange(128, dtype=np.int32))
-        out = fused_shuffle_reduce(k2, mk, vals, valid, sign, keys,
-                                   out_dtype=jnp.float32, tile=128, kblk=128)
-        live = np.asarray(out[3])
-        v_s = np.asarray(out[2])[:, 0]
+        keys = jnp.asarray(np.arange(2048, dtype=np.int32))
+        out = ops.shuffle_reduce(self._Sum(), k2, mk, vals, valid, sign,
+                                 keys, backend="pallas")
+        live = np.asarray(out.live)
+        v_s = np.asarray(out.values)[:, 0]
         # exactly one live row per key, and it is the last-arriving copy
         assert live.sum() == n // reps
         np.testing.assert_array_equal(
@@ -308,3 +360,11 @@ class TestSpmv:
         want = spmv_ell_ref(jnp.asarray(nbrs), jnp.asarray(contrib), v)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-4, atol=1e-4)
+
+
+def test_peaks_keyed_by_device_kind():
+    from repro.launch.mesh import PEAK_FLOPS, V5E, peaks
+    assert peaks(V5E)["flops_bf16"] == PEAK_FLOPS == 197e12
+    assert peaks(V5E)["hbm_bw"] == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")

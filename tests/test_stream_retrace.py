@@ -138,11 +138,12 @@ def test_retraced_batches_marked_in_metrics():
     assert ss.scheduler.compile_skips == 1
 
 
-def test_persistent_cache_dir_wired(tmp_path):
+def test_persistent_cache_dir_wired(tmp_path, monkeypatch):
     """RunConfig(compilation_cache_dir=...) must flip JAX's persistent
     compilation cache on and populate the directory with executables."""
     import jax
 
+    monkeypatch.delenv(jitcache.CACHE_ENV, raising=False)
     cache = tmp_path / "xc"
     rng = np.random.default_rng(5)
     docs = rng.integers(0, VOCAB, (16, L)).astype(np.int32)
@@ -159,3 +160,18 @@ def test_persistent_cache_dir_wired(tmp_path):
     assert jitcache.persistent_cache_dir() == str(cache)
     assert any(cache.iterdir()), "no executables written to the cache dir"
     np.testing.assert_array_equal(ss.result["c"], wc.oracle(mirror, VOCAB))
+
+
+def test_persistent_cache_env_wins(tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, that directory is the cache and
+    a caller's directory is ignored: no other directory is configured."""
+    import jax
+
+    monkeypatch.setattr(jitcache, "_cache_dir", None)
+    monkeypatch.setenv(jitcache.CACHE_ENV, str(tmp_path / "env"))
+    before = jax.config.jax_compilation_cache_dir
+    other = tmp_path / "other"
+    assert jitcache.enable_persistent_cache(other) == str(tmp_path / "env")
+    assert jitcache.persistent_cache_dir() == str(tmp_path / "env")
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not other.exists()

@@ -1,0 +1,110 @@
+"""Native (Mosaic) compiles of the engine kernels for a described TPU v5e.
+
+Interpret mode cannot see what the TPU compiler refuses: unaligned slices,
+gathers, operand types the MXU lacks, VMEM overruns.  These tests compile
+every engine kernel with ``interpret=False`` for one chip of a described
+``v5e:2x2`` topology (no chip attached) at realistic widths, and check
+that the kernel is really in the program (``tpu_custom_call``).  Nothing
+runs, so they say nothing about results or speed.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU compiler's library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.segment_reduce import (
+    segment_minmax_mxu, segment_sum_counts_mxu, segment_sum_mxu,
+)
+from repro.kernels.sort_u32 import SORT_TILE, sort_lex_pallas
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A described chip's executables cannot be read back: keep them out
+    of the persistent cache."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("n", [SORT_TILE, 1 << 16],
+                         ids=["single_tile", "multi_tile"])
+def test_sort_lex(one_chip, n):
+    lane = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    _compile(lambda hi, lo: sort_lex_pallas(hi, lo, interpret=False),
+             lane, lane)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
+def test_segment_sum(one_chip, dtype):
+    n, d, k = 1 << 16, 8, 100_000
+    seg = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    vals = jax.ShapeDtypeStruct((n, d), dtype, sharding=one_chip)
+    _compile(lambda s, v: segment_sum_mxu(s, v, k, out_dtype=dtype,
+                                          interpret=False), seg, vals)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
+def test_segment_sum_counts(one_chip, dtype):
+    n, k = 1 << 16, 32_769
+    seg = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    vals = jax.ShapeDtypeStruct((n, 1), dtype, sharding=one_chip)
+    _compile(lambda s, v: segment_sum_counts_mxu(s, v, k, out_dtype=dtype,
+                                                 interpret=False), seg, vals)
+
+
+@pytest.mark.parametrize("kind", ["min", "max"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
+def test_segment_minmax(one_chip, kind, dtype):
+    n, d, k = 1 << 16, 1, 131_073
+    seg = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    vals = jax.ShapeDtypeStruct((n, d), dtype, sharding=one_chip)
+    _compile(lambda s, v: segment_minmax_mxu(kind, s, v, k, interpret=False),
+             seg, vals)
+
+
+@pytest.mark.parametrize("kind", ["sum", "min"])
+def test_segment_reduce_of_map_output(one_chip, kind, monkeypatch):
+    """The engine's Reduce input through the dispatcher: a [records,
+    fanout] Map output flattened to a row count that is no multiple of the
+    row tile (SSSP's structure carries one extra root record)."""
+    from repro.kernels import ops
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")   # native kernels
+    records, fanout, k = (1 << 15) + 1, 16, 1 << 15
+    grid = lambda dt: jax.ShapeDtypeStruct((records, fanout), dt,
+                                           sharding=one_chip)
+    _compile(lambda s, v, m: ops.segment_reduce(
+        kind, s.reshape(-1), {"v": v.reshape(-1)}, m.reshape(-1), k,
+        backend="pallas"), grid(jnp.int32), grid(jnp.float32),
+        grid(jnp.bool_))
