@@ -1,0 +1,9 @@
+"""device: share of the traced window in which no operation ran on the
+device, 1 - (union of busy intervals / window), mean over the chips."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or t.devices == 0 or t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
