@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.core.incr_iter import IterationLog
 from repro.core.mrbg_store import IOStats
+from repro.core.spans import Span
 
 # engine paths a report can come from
 MODES = (
@@ -78,6 +79,10 @@ class RunReport:
     # the stream layer (None outside streaming): n_in/n_out/n_records/
     # n_inserts/n_deletes/n_cancelled of the CoalesceResult
     coalesce: Optional[Dict[str, int]] = None
+    # host spans (in the order they opened) and counters (h2d_bytes,
+    # d2h_bytes) of this epoch, from repro.core.spans
+    spans: List[Span] = field(default_factory=list)
+    counters: Dict[str, int] = field(default_factory=dict)
     # dense output values; {} when the producer skipped materialization
     # (run/update return reports without it — read session.result instead)
     result: Dict[str, np.ndarray] = field(default_factory=dict)

@@ -43,6 +43,7 @@ from repro.core.incremental import (
 from repro.core.iterative import IterSpec, State, run_iterative, run_plain
 from repro.core.kvstore import KV, edges_to_host, next_bucket
 from repro.core.mrbg_store import IOStats, MRBGStore
+from repro.core.spans import span, take
 from repro.kernels import jitcache
 
 Spec = Union[JobSpec, IterSpec]
@@ -105,15 +106,17 @@ class Session:
             raise RuntimeError("update() before run(); execute the initial "
                                "job first")
         t0 = time.perf_counter()
-        # bucket the delta's row capacity so the jitted refresh path traces
-        # once per power-of-two bucket, not once per distinct row count
-        # (multi-source query deltas arrive as {source: DeltaKV}; the query
-        # driver buckets each encoded feed itself)
-        if isinstance(delta, DeltaKV):
-            cap = next_bucket(delta.capacity, self.config.delta_bucket_min)
-            if cap != delta.capacity:
-                delta = pad_delta(delta, cap)
-        self._driver.update(delta)
+        with span("repro.session.update", epoch=self.epoch + 1):
+            # bucket the delta's row capacity so the jitted refresh path
+            # traces once per power-of-two bucket, not once per distinct
+            # row count (multi-source query deltas arrive as {source:
+            # DeltaKV}; the query driver buckets each encoded feed itself)
+            if isinstance(delta, DeltaKV):
+                cap = next_bucket(delta.capacity,
+                                  self.config.delta_bucket_min)
+                if cap != delta.capacity:
+                    delta = pad_delta(delta, cap)
+            self._driver.update(delta)
         self.epoch += 1
         return self._finish(t0)
 
@@ -170,6 +173,7 @@ class Session:
         # an O(|D|) device->host transfer even when nobody reads it
         rep = self.report(include_result=False)
         rep.seconds = time.perf_counter() - t0
+        rep.spans, rep.counters = take()
         self._last = rep
         self.history.append(rep)
         if len(self.history) > self.config.report_history:

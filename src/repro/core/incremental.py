@@ -33,6 +33,7 @@ from repro.core.kvstore import (
     next_bucket, sort_edges,
 )
 from repro.core.mrbg_store import MRBGStore
+from repro.core.spans import span, to_device, to_host
 from repro.kernels import jitcache, ops
 
 
@@ -65,15 +66,15 @@ def make_delta(record_ids, values, sign, *, keys=None,
     pre-``repro.api`` positional order is no longer accepted: ``keys`` and
     ``valid`` are keyword-only).
     """
-    record_ids = jnp.asarray(record_ids, jnp.int32)
+    record_ids = to_device(record_ids, jnp.int32)
     if keys is None:
         keys = record_ids
-    keys = jnp.asarray(keys, jnp.int32)
+    keys = to_device(keys, jnp.int32)
     if valid is None:
         valid = jnp.ones(keys.shape[0], jnp.bool_)
     return DeltaKV(keys, record_ids,
-                   jax.tree.map(jnp.asarray, values),
-                   jnp.asarray(valid, jnp.bool_), jnp.asarray(sign, jnp.int8))
+                   jax.tree.map(to_device, values),
+                   to_device(valid, jnp.bool_), to_device(sign, jnp.int8))
 
 
 def pad_delta(delta: DeltaKV, capacity: int) -> DeltaKV:
@@ -106,10 +107,11 @@ def apply_delta_host(keys: np.ndarray, values: Dict[str, np.ndarray],
     The mirror plays the role of the partitioned input file on HDFS: '-'
     rows invalidate a record slot, '+' rows (re)write it.
     """
-    rid = np.asarray(delta.record_ids)
-    sgn = np.asarray(delta.sign)
-    dvalid = np.asarray(delta.valid)
-    dkeys = np.asarray(delta.keys)
+    rid = to_host(delta.record_ids)
+    sgn = to_host(delta.sign)
+    dvalid = to_host(delta.valid)
+    dkeys = to_host(delta.keys)
+    dvals = {n: to_host(delta.values[n]) for n in values}
     for i in np.nonzero(dvalid)[0]:
         r = int(rid[i])
         if sgn[i] < 0:
@@ -118,7 +120,7 @@ def apply_delta_host(keys: np.ndarray, values: Dict[str, np.ndarray],
             valid[r] = True
             keys[r] = int(dkeys[i])
             for n, a in values.items():
-                a[r] = np.asarray(delta.values[n])[i]
+                a[r] = dvals[n][i]
 
 
 class ResultView:
@@ -249,38 +251,46 @@ def incremental_onestep(spec: JobSpec, delta: DeltaKV, store: MRBGStore,
     """One incremental refresh; patches ``view`` and ``store`` in place."""
     bk = ops.resolve_backend(backend)
     # 1-2) incremental Map + shuffle of the delta MRBGraph
-    delta_edges = _delta_map((spec.map_fn, bk), delta)
-    dh = edges_to_host(delta_edges, sorted_valid_first=True)
+    with span("repro.incremental.delta_map"):
+        delta_edges = _delta_map((spec.map_fn, bk), delta)
+        dh = edges_to_host(delta_edges, sorted_valid_first=True)
 
-    # 3) affected keys, queried against the store in sorted order
-    affected = np.unique(dh["k2"])
+    # 3) affected keys, queried against the store in sorted order (the
+    # feed, the host work that builds the merge's input, is timed on both
+    # sides of the query)
+    with span("repro.incremental.feed"):
+        affected = np.unique(dh["k2"])
     if affected.size == 0:
         return {"affected": 0, "merged": 0}
     pk2, pmk, pv2, _plen = store.query(affected)
-    if pv2 is None:
-        pv2 = {n: np.zeros((0,) + a.shape[1:], a.dtype)
-               for n, a in _v2_dict(dh["v2"]).items()}
 
     # 4-5) pad to buckets and run the jitted merge+reduce
-    key_cap = next_bucket(affected.size, 64)
-    dsign = np.asarray(dh["sign"], np.int8)
-    combined = _combine_edges(pk2, pmk, pv2,
-                              dh["k2"], dh["mk"], _v2_dict(dh["v2"]), dsign)
-    keys_pad = np.full(key_cap, np.int32(2**31 - 1), np.int32)
-    keys_pad[:affected.size] = affected.astype(np.int32)
+    with span("repro.incremental.feed"):
+        if pv2 is None:
+            pv2 = {n: np.zeros((0,) + a.shape[1:], a.dtype)
+                   for n, a in _v2_dict(dh["v2"]).items()}
+        key_cap = next_bucket(affected.size, 64)
+        dsign = np.asarray(dh["sign"], np.int8)
+        combined = _combine_edges(pk2, pmk, pv2, dh["k2"], dh["mk"],
+                                  _v2_dict(dh["v2"]), dsign)
+        keys_pad = np.full(key_cap, np.int32(2**31 - 1), np.int32)
+        keys_pad[:affected.size] = affected.astype(np.int32)
+        keys_dev = to_device(keys_pad)
 
-    merged, values, counts = _merge_reduce(spec.reducer, key_cap, bk,
-                                           combined, jnp.asarray(keys_pad))
+    with span("repro.incremental.merge"):
+        merged, values, counts = _merge_reduce(spec.reducer, key_cap, bk,
+                                               combined, keys_dev)
+        mh = edges_to_host(merged)
 
     # 6) preserve merged chunks + patch results
-    mh = edges_to_host(merged)
     store.append(mh["k2"], mh["mk"], _v2_dict(mh["v2"]))
-    counts_h = np.asarray(counts)[:affected.size]
+    with span("repro.incremental.patch"):
+        counts_h = to_host(counts)[:affected.size]
+        vals_h = {n: to_host(a)[:affected.size]
+                  for n, a in _v2_dict(values).items()}
+        view.patch(affected, vals_h, counts_h)
     gone = affected[counts_h == 0]
     store.mark_deleted(gone)
-    vals_h = {n: np.asarray(a)[:affected.size]
-              for n, a in _v2_dict(values).items()}
-    view.patch(affected, vals_h, counts_h)
     return {"affected": int(affected.size), "merged": int(mh["k2"].shape[0]),
             "deleted_keys": int(gone.size)}
 
@@ -330,6 +340,6 @@ def _combine_edges(pk2: np.ndarray, pmk: np.ndarray,
         buf = np.zeros((cap,) + a.shape[1:], a.dtype)
         buf[:n_p] = pv2[name]; buf[n_p:n_p + n_d] = a
         out_v2[name] = buf
-    return Edges(jnp.asarray(out_k2), jnp.asarray(out_mk),
-                 jax.tree.map(jnp.asarray, out_v2),
-                 jnp.asarray(valid), jnp.asarray(out_sign))
+    return Edges(to_device(out_k2), to_device(out_mk),
+                 jax.tree.map(to_device, out_v2),
+                 to_device(valid), to_device(out_sign))

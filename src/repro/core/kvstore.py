@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import to_host
 from repro.kernels import ops
 
 INVALID_KEY = jnp.int32(2**31 - 1)
@@ -257,17 +258,17 @@ def edges_to_host(edges: Edges, *, sorted_valid_first: bool = False) -> dict:
     presence tests) often fill <10% of their static edge buffer.
     """
     if sorted_valid_first:
-        nvalid = int(jnp.sum(edges.valid))
+        nvalid = int(to_host(jnp.sum(edges.valid)))
         cap = min(edges.capacity, next_bucket(max(nvalid, 1), 64))
         sl = lambda a: a[:cap]
         edges = Edges(sl(edges.k2), sl(edges.mk),
                       jax.tree.map(sl, edges.v2), sl(edges.valid),
                       sl(edges.sign))
-    valid = np.asarray(edges.valid)
+    valid = to_host(edges.valid)
     idx = np.nonzero(valid)[0]
     return {
-        "k2": np.asarray(edges.k2)[idx],
-        "mk": np.asarray(edges.mk)[idx],
-        "v2": jax.tree.map(lambda l: np.asarray(l)[idx], edges.v2),
-        "sign": np.asarray(edges.sign)[idx],
+        "k2": to_host(edges.k2)[idx],
+        "mk": to_host(edges.mk)[idx],
+        "v2": jax.tree.map(lambda l: to_host(l)[idx], edges.v2),
+        "sign": to_host(edges.sign)[idx],
     }

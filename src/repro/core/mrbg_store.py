@@ -29,6 +29,8 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.core.spans import span
+
 # Default knobs (paper: T = 100KB; cache sized like Hadoop's io.sort.mb scale)
 DEFAULT_GAP_T = 100 * 1024
 DEFAULT_CACHE = 4 * 1024 * 1024
@@ -138,34 +140,36 @@ class MRBGStore:
         """Append a merge pass's output chunks as a new sorted batch and
         repoint the index (old chunk versions become obsolete in place,
         Section 3.4 'Incremental Storage of MRBGraph Changes')."""
-        k2 = np.asarray(k2, np.int32)
-        if k2.size == 0:
-            return
-        mk = np.asarray(mk, np.int32)
-        if sign is None:
-            sign = np.ones(k2.shape[0], np.int8)
-        batch = _Batch(k2, mk, {n: np.asarray(a) for n, a in v2.items()},
-                       np.asarray(sign, np.int8), self.file_records)
-        bid = len(self.batches)
-        self.batches.append(batch)
-        self.file_records += batch.size
+        with span("repro.mrbg_store.append"):
+            k2 = np.asarray(k2, np.int32)
+            if k2.size == 0:
+                return
+            mk = np.asarray(mk, np.int32)
+            if sign is None:
+                sign = np.ones(k2.shape[0], np.int8)
+            batch = _Batch(k2, mk, {n: np.asarray(a) for n, a in v2.items()},
+                           np.asarray(sign, np.int8), self.file_records)
+            bid = len(self.batches)
+            self.batches.append(batch)
+            self.file_records += batch.size
 
-        # chunk boundaries within the sorted batch
-        keys, starts, lens = _chunk_spans(k2)
-        self.live_records -= int(self.idx_len[keys].sum())
-        self.idx_batch[keys] = bid
-        self.idx_start[keys] = starts
-        self.idx_len[keys] = lens
-        self.live_records += int(lens.sum())
+            # chunk boundaries within the sorted batch
+            keys, starts, lens = _chunk_spans(k2)
+            self.live_records -= int(self.idx_len[keys].sum())
+            self.idx_batch[keys] = bid
+            self.idx_start[keys] = starts
+            self.idx_len[keys] = lens
+            self.live_records += int(lens.sum())
 
     def mark_deleted(self, keys: np.ndarray) -> None:
         """Drop keys whose chunks became empty after a merge."""
-        keys = np.asarray(keys, np.int32)
-        if keys.size == 0:
-            return
-        self.live_records -= int(self.idx_len[keys].sum())
-        self.idx_batch[keys] = -1
-        self.idx_len[keys] = 0
+        with span("repro.mrbg_store.append"):
+            keys = np.asarray(keys, np.int32)
+            if keys.size == 0:
+                return
+            self.live_records -= int(self.idx_len[keys].sum())
+            self.idx_batch[keys] = -1
+            self.idx_len[keys] = 0
 
     # -- retrieval --------------------------------------------------------
     def query(self, keys_sorted: np.ndarray):
@@ -176,24 +180,25 @@ class MRBGStore:
         ``self.stats``; data physically flows through read-cache buffers so
         that wall time follows bytes_read + n_reads.
         """
-        keys = np.asarray(keys_sorted, np.int64)
-        present = keys[(keys >= 0) & (keys < self.num_keys)]
-        present = present[self.idx_batch[present] >= 0]
-        per_key_len = np.zeros(keys.shape[0], np.int32)
-        mask = (keys >= 0) & (keys < self.num_keys)
-        valid_keys = keys[mask]
-        lens = np.where(self.idx_batch[valid_keys] >= 0,
-                        self.idx_len[valid_keys], 0)
-        per_key_len[mask] = lens
+        with span("repro.mrbg_store.query"):
+            keys = np.asarray(keys_sorted, np.int64)
+            present = keys[(keys >= 0) & (keys < self.num_keys)]
+            present = present[self.idx_batch[present] >= 0]
+            per_key_len = np.zeros(keys.shape[0], np.int32)
+            mask = (keys >= 0) & (keys < self.num_keys)
+            valid_keys = keys[mask]
+            lens = np.where(self.idx_batch[valid_keys] >= 0,
+                            self.idx_len[valid_keys], 0)
+            per_key_len[mask] = lens
 
-        if present.size == 0:
-            empty_v2 = None
-            return (np.zeros(0, np.int32), np.zeros(0, np.int32), empty_v2,
-                    per_key_len)
+            if present.size == 0:
+                empty_v2 = None
+                return (np.zeros(0, np.int32), np.zeros(0, np.int32),
+                        empty_v2, per_key_len)
 
-        plan = self._plan_reads(present)
-        out_k2, out_mk, out_v2 = self._execute_reads(present, plan)
-        return out_k2, out_mk, out_v2, per_key_len
+            plan = self._plan_reads(present)
+            out_k2, out_mk, out_v2 = self._execute_reads(present, plan)
+            return out_k2, out_mk, out_v2, per_key_len
 
     # The read planner implements Algorithm 1 (+ the Section 5.2
     # multi-dynamic-window extension).  It returns, for each requested key,

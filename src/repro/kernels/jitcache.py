@@ -8,13 +8,16 @@ survivable:
   * **Trace counters** — every jitted kernel on the refresh path calls
     :func:`count_trace` at the top of its Python body.  A jit body only
     executes when JAX is *tracing* (a jit-cache miss), so the counter is
-    an exact retrace count with zero steady-state overhead.  The
-    monotonically increasing :func:`generation` lets a caller bracket a
-    region ("did this refresh trace anything?") — the stream scheduler
-    uses it to exclude compile-polluted cost observations.
+    an exact retrace count with zero steady-state overhead, read per
+    kernel by :func:`trace_counts`.  The monotonically increasing
+    :func:`generation` lets a caller bracket a region ("did this refresh
+    trace anything?") — the stream scheduler uses it to exclude
+    compile-polluted cost observations.
   * **Compile counters** — a ``jax.monitoring`` listener counts actual
-    XLA backend compiles (a persistent-cache hit traces but does not
-    compile, so the two counters differ exactly by the cache's hits).
+    XLA backend compiles and their seconds (a persistent-cache hit traces
+    but does not compile, so the two counters differ exactly by the
+    cache's hits); :func:`snapshot` reads the totals of both counters,
+    :func:`compile_seconds_total` the compile seconds.
   * **Persistent compilation cache** — :func:`enable_persistent_cache`
     points JAX's disk cache at ``JAX_COMPILATION_CACHE_DIR`` when that is
     set, else at the caller's directory (``RunConfig(
@@ -63,16 +66,6 @@ def trace_counts() -> Dict[str, int]:
     """Per-kernel retrace counts since process start."""
     with _lock:
         return dict(_traces)
-
-
-def traces_total() -> int:
-    with _lock:
-        return sum(_traces.values())
-
-
-def compiles_total() -> int:
-    """XLA backend compiles since :func:`install_compile_listener`."""
-    return _compiles
 
 
 def compile_seconds_total() -> float:

@@ -301,24 +301,32 @@ def shuffle_reduce(reducer, k2: jax.Array, mk: jax.Array, values: Any,
     bk = resolve_backend(backend)
     n = k2.shape[0]
     key_cap = affected_keys.shape[0]
-    k2m = jnp.where(valid, k2, jnp.int32(_INT32_MAX))
-    res = sort_pairs(k2m, mk, (values, valid, sign), num_keys=2, backend=bk)
-    vals_s, valid_s, sign_s = res.payload
+    # each stage is a named scope, so that a profile names the device time
+    # of its operations (the scope path is their op_name metadata)
+    with jax.named_scope("shuffle_reduce"):
+        with jax.named_scope("sort"):
+            k2m = jnp.where(valid, k2, jnp.int32(_INT32_MAX))
+            res = sort_pairs(k2m, mk, (values, valid, sign), num_keys=2,
+                             backend=bk)
+        vals_s, valid_s, sign_s = res.payload
 
-    # last-writer-wins per (k2, mk); tombstones delete
-    nk2 = jnp.roll(res.k2, -1)
-    nmk = jnp.roll(res.mk, -1)
-    is_last = jnp.logical_or(
-        jnp.arange(n) == n - 1,
-        jnp.logical_or(nk2 != res.k2, nmk != res.mk))
-    live = valid_s & is_last & (sign_s > 0)
+        # last-writer-wins per (k2, mk); tombstones delete
+        with jax.named_scope("last_writer"):
+            nk2 = jnp.roll(res.k2, -1)
+            nmk = jnp.roll(res.mk, -1)
+            is_last = jnp.logical_or(
+                jnp.arange(n) == n - 1,
+                jnp.logical_or(nk2 != res.k2, nmk != res.mk))
+            live = valid_s & is_last & (sign_s > 0)
 
-    # route each live row to its affected-key slot
-    local = jnp.searchsorted(affected_keys, res.k2).astype(jnp.int32)
-    in_set = jnp.take(affected_keys,
-                      jnp.clip(local, 0, key_cap - 1)) == res.k2
-    acc, counts = segment_reduce(reducer, local, vals_s, live & in_set,
-                                 key_cap, backend=bk)
+        # route each live row to its affected-key slot
+        with jax.named_scope("route"):
+            local = jnp.searchsorted(affected_keys, res.k2).astype(jnp.int32)
+            in_set = jnp.take(affected_keys,
+                              jnp.clip(local, 0, key_cap - 1)) == res.k2
+        with jax.named_scope("reduce"):
+            acc, counts = segment_reduce(reducer, local, vals_s,
+                                         live & in_set, key_cap, backend=bk)
     return ShuffleReduced(res.k2, res.mk, vals_s, live, res.perm, acc,
                           counts)
 

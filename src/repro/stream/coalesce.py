@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.core.incremental import DeltaKV, make_delta
 from repro.core.kvstore import INVALID_KEY, next_bucket
+from repro.core.spans import to_device, to_host
 from repro.kernels import jitcache, ops
 
 
@@ -88,13 +89,12 @@ def coalesce_rows(record_ids: np.ndarray, values: Dict[str, np.ndarray],
     valid[:n] = True
 
     perm, keep, firsts, net, cnt = _coalesce_kernel(
-        cap, bk, jnp.asarray(rid_pad), jnp.asarray(sg_pad),
-        jnp.asarray(valid))
-    perm = np.asarray(perm)
-    keep = np.asarray(keep)
-    firsts = np.asarray(firsts)
-    net = np.asarray(net)
-    cnt = np.asarray(cnt)
+        cap, bk, to_device(rid_pad), to_device(sg_pad), to_device(valid))
+    perm = to_host(perm)
+    keep = to_host(keep)
+    firsts = to_host(firsts)
+    net = to_host(net)
+    cnt = to_host(cnt)
 
     # host compaction: surviving rows in (record id, arrival) order
     sel = perm[keep]

@@ -77,10 +77,6 @@ class StreamMetrics:
         idx = min(len(s) - 1, max(0, int(round(p / 100.0 * (len(s) - 1)))))
         return s[idx]
 
-    def latency_pct(self, p: float) -> float:
-        with self._lock:
-            return self._pct(self._latencies, p)
-
     def refresh_pct(self, p: float) -> float:
         with self._lock:
             return self._pct(self._refresh_seconds, p)

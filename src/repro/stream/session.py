@@ -28,6 +28,7 @@ from repro.api.config import RunConfig, StreamConfig
 from repro.api.session import Session, Spec
 from repro.core.incremental import apply_delta_host, make_delta
 from repro.core.kvstore import KV, next_bucket
+from repro.core.spans import span
 from repro.kernels import jitcache
 from repro.stream.coalesce import (
     CoalesceResult, coalesce, coalesce_rows, concat_records,
@@ -432,8 +433,10 @@ class StreamSession:
         return prep.decision.action
 
     def _process_batch(self) -> None:
-        with self._lock:
-            prep = self.prepare_batch()
+        with self._lock, span("repro.stream.step",
+                              epoch=self.session.epoch + 1):
+            with span("repro.stream.prepare"):
+                prep = self.prepare_batch()
             if prep is not None:
                 self.execute_prepared(prep)
 
