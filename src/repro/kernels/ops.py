@@ -217,6 +217,12 @@ def _segment_reduce_pallas(kind, seg, values, valid, num_segments):
         segment_sum_mxu,
     )
     interp = _interpret()
+    # the kernels take [rows, width] value columns, which the TPU pads to
+    # 128 lanes (128x at width 1).  Fused into the masking of such a
+    # column, the producers of the ids and the mask would have each of
+    # their inputs copied into that layout (4 GiB a lane at 2^23 rows, past
+    # one v5e's memory in the merge), so both are materialized first
+    seg, valid = jax.lax.optimization_barrier((seg, valid))
     # pad the rows while each leaf still has its own shape: the kernels
     # take [rows, width] columns, and on TPU padding a column that was just
     # reshaped from a wider array compiles in time linear in its length
@@ -283,6 +289,26 @@ class ShuffleReduced(NamedTuple):
 _INT32_MAX = 2**31 - 1
 
 
+def _route(k2: jax.Array, affected_keys: jax.Array):
+    """Each row's slot among ``affected_keys``, and whether its key is one.
+
+    ``k2`` ascends, so each affected key owns the run [start, end) of rows
+    equal to it.  A row's slot, ``searchsorted(affected_keys, k2)``, is the
+    number of runs that end at or before it, and its key is affected where
+    more runs have started than ended: key_cap searches into the rows and
+    one prefix sum over them, O(n + key_cap log n), where a search per row
+    costs O(n log key_cap).  An end at n, past the last row, is dropped.
+    """
+    n = k2.shape[0]
+    starts = jnp.searchsorted(k2, affected_keys, side="left")
+    ends = jnp.searchsorted(k2, affected_keys, side="right")
+    marks = (jnp.zeros((2, n), jnp.int32)
+             .at[0, starts].add(1, mode="drop")
+             .at[1, ends].add(1, mode="drop"))
+    started, local = jnp.cumsum(marks, axis=1)
+    return local, started > local
+
+
 def shuffle_reduce(reducer, k2: jax.Array, mk: jax.Array, values: Any,
                    valid: jax.Array, sign: jax.Array,
                    affected_keys: jax.Array, *,
@@ -297,6 +323,9 @@ def shuffle_reduce(reducer, k2: jax.Array, mk: jax.Array, values: Any,
     per slot, mean division stays with ``finalize_reduce``).  Both
     backends run the same composition (:func:`sort_pairs`, then
     :func:`segment_reduce`), so the xla path is the bitwise reference.
+
+    The route to the slots (:func:`_route`) requires the sorted ``k2`` to
+    ascend, which :func:`sort_pairs` guarantees on both backends.
     """
     bk = resolve_backend(backend)
     n = k2.shape[0]
@@ -321,9 +350,7 @@ def shuffle_reduce(reducer, k2: jax.Array, mk: jax.Array, values: Any,
 
         # route each live row to its affected-key slot
         with jax.named_scope("route"):
-            local = jnp.searchsorted(affected_keys, res.k2).astype(jnp.int32)
-            in_set = jnp.take(affected_keys,
-                              jnp.clip(local, 0, key_cap - 1)) == res.k2
+            local, in_set = _route(res.k2, affected_keys)
         with jax.named_scope("reduce"):
             acc, counts = segment_reduce(reducer, local, vals_s,
                                          live & in_set, key_cap, backend=bk)

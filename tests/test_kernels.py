@@ -1,5 +1,7 @@
 """Pallas kernel sweeps: shapes x dtypes vs the pure-jnp oracles
 (interpret mode on CPU; identical code lowers natively on TPU)."""
+import re
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -345,6 +347,111 @@ class TestShuffleReduce:
             counts = np.asarray(sr.counts)
             assert counts[0] == 0 and counts[1] == 1
             assert np.asarray(sr.acc)[1, 0] == 7.0
+
+
+def _search_route(k2, affected_keys):
+    """The reference route, a binary search per row: each row's key
+    searched among the affected keys, then looked up."""
+    key_cap = affected_keys.shape[0]
+    local = jnp.searchsorted(affected_keys, k2).astype(jnp.int32)
+    in_set = jnp.take(affected_keys, jnp.clip(local, 0, key_cap - 1)) == k2
+    return local, in_set
+
+
+def _route_case(name):
+    """(k2, mk, values, valid, sign, affected_keys) of one route case."""
+    from repro.serve.batch import MAX_GLOBAL_KEY
+    rng = np.random.default_rng(len(name))
+    n, nkeys, cap, pad = 300, 40, 64, 2**31 - 1
+    k2 = rng.integers(0, nkeys, n)
+    valid = rng.random(n) < 0.8
+    aff = np.unique(k2[valid])
+    if name == "absent_keys":           # empty runs below, among, above
+        aff = np.concatenate([[-5], np.arange(0, nkeys, 2), [nkeys + 9]])
+    elif name == "all_invalid":
+        valid[:] = False
+    elif name == "n_eq_key_cap":        # one row per slot
+        n = cap
+        k2 = rng.permutation(2 * cap)[:n]
+        valid = np.ones(n, bool)
+        aff = np.sort(k2)
+    elif name == "runs_at_both_ends":   # first and last rows in runs
+        valid[:] = True
+        aff = np.unique(k2)
+    elif name == "serve_global_keys":   # tenant lanes, serve's pad key
+        tenant = rng.integers(0, 3, n)
+        k2 = k2 + tenant * (1 << 28)
+        aff = np.unique(k2[valid])[::2]
+        pad = MAX_GLOBAL_KEY
+    keys = np.full(cap, pad, np.int32)
+    keys[:aff.size] = aff
+    mk = rng.integers(0, 8, n)
+    vals = rng.integers(-20, 20, (n, 2)).astype(np.float32)
+    sign = np.where(rng.random(n) < 0.75, 1, -1).astype(np.int8)
+    return tuple(jnp.asarray(a) for a in (k2.astype(np.int32),
+                                          mk.astype(np.int32), vals, valid,
+                                          sign, keys))
+
+
+ROUTE_CASES = ["absent_keys", "all_invalid", "n_eq_key_cap",
+               "runs_at_both_ends", "int32_max_pads", "serve_global_keys"]
+
+
+class TestRoute:
+    """The route from run boundaries (``ops._route``) against a binary
+    search per row: the same slot and membership for every row,
+    and ``ops.shuffle_reduce`` through either bitwise equal on every
+    output, on both backends."""
+
+    class _Sum:
+        kind = "sum"
+
+    @pytest.mark.parametrize("case", ROUTE_CASES)
+    def test_route_matches_search_on_every_row(self, case):
+        from repro.kernels import ops
+        k2, _, _, valid, _, keys = _route_case(case)
+        rows = jnp.sort(jnp.where(valid, k2, jnp.int32(2**31 - 1)))
+        for got, want, name in zip(ops._route(rows, keys),
+                                   _search_route(rows, keys),
+                                   ("local", "in_set")):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                          err_msg=name)
+
+    @pytest.mark.parametrize("backend", ["xla", "pallas"])
+    @pytest.mark.parametrize("case", ROUTE_CASES)
+    def test_shuffle_reduce_matches_search_route(self, case, backend,
+                                                 monkeypatch):
+        from repro.kernels import ops
+        args = _route_case(case)
+        got = ops.shuffle_reduce(self._Sum(), *args, backend=backend)
+        monkeypatch.setattr(ops, "_route", _search_route)
+        want = ops.shuffle_reduce(self._Sum(), *args, backend=backend)
+        for name in ops.ShuffleReduced._fields:
+            np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                          np.asarray(getattr(want, name)),
+                                          err_msg=name)
+
+    def test_no_gather_per_row(self):
+        """The compiled merge's route gathers only key_cap results (the
+        searches of the keys in the rows), never one per row: the per-row
+        search stays gone."""
+        from repro.core.incremental import _merge_reduce
+        from repro.core.kvstore import Edges, sum_reducer
+        n, key_cap = 1 << 14, 1024
+        lane = lambda dt: jax.ShapeDtypeStruct((n,), dt)
+        combined = Edges(lane(jnp.int32), lane(jnp.int32),
+                         {"c": lane(jnp.float32)}, lane(jnp.bool_),
+                         lane(jnp.int8))
+        text = _merge_reduce.lower(
+            sum_reducer(), key_cap, "xla", combined,
+            jax.ShapeDtypeStruct((key_cap,), jnp.int32)).compile().as_text()
+        shapes = [re.search(r"= \w+\[([\d,]*)\]", line).group(1)
+                  for line in text.splitlines()
+                  if " gather(" in line and "/shuffle_reduce/route/" in line]
+        assert shapes, "no gather in the route scope: the searches moved?"
+        for dims in shapes:
+            rows = np.prod([int(d) for d in dims.split(",") if d])
+            assert rows <= key_cap, f"route gathers [{dims}]"
 
 
 class TestSpmv:

@@ -108,3 +108,26 @@ def test_segment_reduce_of_map_output(one_chip, kind, monkeypatch):
         kind, s.reshape(-1), {"v": v.reshape(-1)}, m.reshape(-1), k,
         backend="pallas"), grid(jnp.int32), grid(jnp.float32),
         grid(jnp.bool_))
+
+
+def test_merge_at_benchmark_bucket_fits_one_chip(one_chip, monkeypatch):
+    """The whole merge (sort, last writer, route, reduce) at the chip
+    benchmark's bucket: 2^23 rows of wordcount's [rows, 1] value column
+    into 1,024 key slots.  The compiler refuses a program past the chip's
+    memory, where the padded layout of a [rows, 1] column would take it if
+    it spread to the producers of the reduce's ids and mask."""
+    from repro.core.incremental import _merge_reduce
+    from repro.core.kvstore import Edges, sum_reducer
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")   # native kernels
+    n, key_cap = 1 << 23, 1024
+    lane = lambda dt, *width: jax.ShapeDtypeStruct((n,) + width, dt,
+                                                   sharding=one_chip)
+    combined = Edges(lane(jnp.int32), lane(jnp.int32),
+                     {"c": lane(jnp.float32, 1)}, lane(jnp.bool_),
+                     lane(jnp.int8))
+    keys = jax.ShapeDtypeStruct((key_cap,), jnp.int32, sharding=one_chip)
+    compiled = _merge_reduce.lower(sum_reducer(), key_cap, "pallas",
+                                   combined, keys).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
