@@ -5,7 +5,8 @@ distributed) funnels its shuffle and Reduce work through the two entry
 points here:
 
   * :func:`sort_pairs`      — lexicographic stable sort of (k2, mk) with a
-    permutation output; arbitrary pytree payloads are gathered once.
+    permutation output; up to three bool flags ride the sort itself, and
+    arbitrary pytree payloads are gathered once.
   * :func:`segment_reduce`  — segment reduction for all four ``Reducer``
     monoids (sum / min / max / mean) over pytree values, with an explicit
     validity mask and per-segment counts.
@@ -112,37 +113,56 @@ class SortedPairs(NamedTuple):
     mk: jax.Array        # [N] co-sorted secondary keys
     payload: Any         # pytree of [N, ...] gathered through perm
     perm: jax.Array      # [N] int32, k2_sorted == k2[perm]
+    flags: tuple = ()    # [N] bool lanes co-sorted by the sort itself
+
+
+MAX_FLAGS = 3
 
 
 def sort_pairs(k2: jax.Array, mk: Optional[jax.Array] = None,
                payload: Any = None, *, num_keys: int = 2,
-               backend: Optional[str] = None) -> SortedPairs:
+               flags: tuple = (), backend: Optional[str] = None
+               ) -> SortedPairs:
     """Stable lexicographic sort by (k2[, mk]); ties keep input order.
 
     Validity is the caller's concern: mask invalid rows' k2 to INVALID_KEY
     beforehand and they sort to the tail.  ``payload`` may be any pytree of
     [N, ...] arrays; every leaf is gathered once through the permutation.
+    ``flags``, at most ``MAX_FLAGS`` bool [N] lanes, come back co-sorted
+    without a gather: on pallas they ride in the low bits of the network's
+    index lane, on xla they are extra sort operands after the keys.
     """
     bk = resolve_backend(backend)
     n = k2.shape[0]
+    flags = tuple(jnp.asarray(f, jnp.bool_) for f in flags)
+    if len(flags) > MAX_FLAGS:
+        raise ValueError(
+            f"at most {MAX_FLAGS} flags ride the sort, got {len(flags)}")
     if mk is None:
         mk = jnp.zeros(n, jnp.int32)
         num_keys = 1
     if bk == "pallas":
         from repro.kernels.sort_u32 import sort_lex_pallas
         lo = mk if num_keys >= 2 else jnp.zeros(n, jnp.int32)
-        k2s, los, perm = sort_lex_pallas(k2, lo, interpret=_interpret())
+        if flags:
+            tag = sum(f.astype(jnp.int32) << i for i, f in enumerate(flags))
+            k2s, los, perm, tag = sort_lex_pallas(
+                k2, lo, interpret=_interpret(), tag=tag,
+                tag_bits=len(flags))
+            flags = tuple(((tag >> i) & 1).astype(bool)
+                          for i in range(len(flags)))
+        else:
+            k2s, los, perm = sort_lex_pallas(k2, lo, interpret=_interpret())
         mks = los if num_keys >= 2 else jnp.take(mk, perm, axis=0)
     else:
         iota = jnp.arange(n, dtype=jnp.int32)
-        if num_keys <= 1:
-            k2s, perm = jax.lax.sort((k2, iota), num_keys=1, is_stable=True)
-        else:
-            k2s, _, perm = jax.lax.sort((k2, mk, iota), num_keys=2,
-                                        is_stable=True)
+        keys = (k2,) if num_keys <= 1 else (k2, mk)
+        out = jax.lax.sort(keys + (iota,) + flags, num_keys=len(keys),
+                           is_stable=True)
+        k2s, perm, flags = out[0], out[len(keys)], tuple(out[len(keys) + 1:])
         mks = jnp.take(mk, perm, axis=0)
     gathered = jax.tree.map(lambda a: jnp.take(a, perm, axis=0), payload)
-    return SortedPairs(k2s, mks, gathered, perm)
+    return SortedPairs(k2s, mks, gathered, perm, flags)
 
 
 # ---------------------------------------------------------------------------
@@ -324,6 +344,10 @@ def shuffle_reduce(reducer, k2: jax.Array, mk: jax.Array, values: Any,
     backends run the same composition (:func:`sort_pairs`, then
     :func:`segment_reduce`), so the xla path is the bitwise reference.
 
+    Of ``valid`` and ``sign`` the merge reads one bit each, ``valid`` and
+    ``sign > 0``: they travel through the sort as its ``flags``, and only
+    ``values`` is gathered through the permutation.
+
     The route to the slots (:func:`_route`) requires the sorted ``k2`` to
     ascend, which :func:`sort_pairs` guarantees on both backends.
     """
@@ -335,9 +359,10 @@ def shuffle_reduce(reducer, k2: jax.Array, mk: jax.Array, values: Any,
     with jax.named_scope("shuffle_reduce"):
         with jax.named_scope("sort"):
             k2m = jnp.where(valid, k2, jnp.int32(_INT32_MAX))
-            res = sort_pairs(k2m, mk, (values, valid, sign), num_keys=2,
-                             backend=bk)
-        vals_s, valid_s, sign_s = res.payload
+            res = sort_pairs(k2m, mk, values, num_keys=2,
+                             flags=(valid, sign > 0), backend=bk)
+        vals_s = res.payload
+        valid_s, positive_s = res.flags
 
         # last-writer-wins per (k2, mk); tombstones delete
         with jax.named_scope("last_writer"):
@@ -346,7 +371,7 @@ def shuffle_reduce(reducer, k2: jax.Array, mk: jax.Array, values: Any,
             is_last = jnp.logical_or(
                 jnp.arange(n) == n - 1,
                 jnp.logical_or(nk2 != res.k2, nmk != res.mk))
-            live = valid_s & is_last & (sign_s > 0)
+            live = valid_s & is_last & positive_s
 
         # route each live row to its affected-key slot
         with jax.named_scope("route"):

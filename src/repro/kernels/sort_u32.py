@@ -14,9 +14,14 @@ unique, the comparison is a total order — which makes the (otherwise
 unstable) bitonic network *stable* with respect to (primary, secondary)
 and lets the index lane double as the output permutation.  The engine's
 merge path (``incremental._merge_reduce``) depends on exactly this
-stability for its last-writer-wins semantics, and arbitrary pytree
-payloads are gathered once through the permutation instead of riding
-through every compare-exchange stage.
+stability for its last-writer-wins semantics.
+
+A few flag bits per row ride under the index lane for free: the lane
+holds ``(row << tag_bits) | tag``, which is still unique and orders
+exactly as ``row`` does, so the network returns the same permutation
+and carries the tag through every compare-exchange stage with the lane
+it moves anyway.  Other payloads are gathered once through the
+permutation instead of riding through every stage.
 
 Inputs larger than one VMEM tile are handled by splitting the global
 bitonic network at tile granularity (``SORT_TILE`` rows per tile):
@@ -199,17 +204,39 @@ def _lane_shapes(m: int, hi_dtype, lo_dtype):
             jax.ShapeDtypeStruct(shape, jnp.int32)]
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def sorted_lanes(hi: jax.Array, lo: jax.Array, *, tile: int,
-                 interpret: bool):
+_INT32_MAX = 2**31 - 1
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("tile", "interpret", "tag_bits"))
+def sorted_lanes(hi: jax.Array, lo: jax.Array, tag: jax.Array | None = None,
+                 *, tile: int, interpret: bool, tag_bits: int = 0):
     """Sort pre-padded (hi, lo) lanes of length ``m``, a power of two of at
     least ``MIN_TILE``, with the row index as the third lane; lengths past
-    ``tile`` run the multi-tile network.  Returns ``(hi, lo, perm)`` flat;
-    the kernels see each lane as ``(m / 128, 128)`` tiles.
+    ``tile`` run the multi-tile network.  A ``tag`` of ``tag_bits`` bits
+    rides under the index, ``(row << tag_bits) | tag``.  Returns ``(hi, lo,
+    perm, tag)`` flat (``tag`` None without one); the kernels see each
+    lane as ``(m / 128, 128)`` tiles.
     """
     jitcache.count_trace("kernels.sort_lex")
     m = hi.shape[0]
+    if (m << tag_bits) - 1 > _INT32_MAX:     # the largest tagged index
+        raise ValueError(
+            f"{m} padded rows with {tag_bits} tag bits overflow the int32 "
+            f"index lane: at most {(_INT32_MAX + 1) >> tag_bits} rows")
     idx = jnp.arange(m, dtype=jnp.int32)
+    if tag_bits:
+        idx = jnp.bitwise_or(jnp.left_shift(idx, tag_bits), tag)
+    hi, lo, idx = _network(hi, lo, idx, tile=tile, interpret=interpret)
+    if not tag_bits:
+        return hi, lo, idx, None
+    return (hi, lo, jnp.right_shift(idx, tag_bits),
+            jnp.bitwise_and(idx, (1 << tag_bits) - 1))
+
+
+def _network(hi, lo, idx, *, tile: int, interpret: bool):
+    """The bitonic network over flat lanes of a power-of-two length."""
+    m = hi.shape[0]
     hi, lo, idx = (a.reshape(m // LANES, LANES) for a in (hi, lo, idx))
     if m <= tile:
         # single tile: the whole network in one launch
@@ -290,7 +317,8 @@ def pad_lanes(hi: jax.Array, lo: jax.Array, m: int):
 
 
 def sort_lex_pallas(hi: jax.Array, lo: jax.Array, *, tile: int = SORT_TILE,
-                    interpret: bool | None = None):
+                    interpret: bool | None = None,
+                    tag: jax.Array | None = None, tag_bits: int = 0):
     """Stable lexicographic sort by (hi, lo); ties broken by row index.
 
     Returns ``(hi_sorted, lo_sorted, perm)`` where ``perm`` is the int32
@@ -301,16 +329,30 @@ def sort_lex_pallas(hi: jax.Array, lo: jax.Array, *, tile: int = SORT_TILE,
     VMEM stays bounded by the tile size (two tiles per cross-stage launch)
     instead of the whole padded input.  The network is compiled once per
     padded length, not once per input length.
+
+    With an int32 ``tag`` of ``tag_bits`` bits (values in ``[0,
+    2**tag_bits)``) the tag rides under the index lane and comes back
+    sorted as a fourth output, ``tag[perm]``, with the same permutation;
+    a padded length past ``2**31 >> tag_bits`` rows raises ValueError.
     """
     if interpret is None:
         interpret = default_interpret()
     if tile & (tile - 1) or tile < MIN_TILE:
         raise ValueError(
             f"tile must be a power of two >= {MIN_TILE}, got {tile}")
+    if tag_bits < 0 or (tag is None) != (tag_bits == 0):
+        raise ValueError(
+            f"a tag needs tag_bits > 0, and tag_bits a tag, got {tag_bits}")
     n = hi.shape[0]
-    hi, lo = pad_lanes(hi, lo, padded_length(n))
-    ho, lo_out, perm = sorted_lanes(hi, lo, tile=tile, interpret=interpret)
-    return ho[:n], lo_out[:n], perm[:n]
+    m = padded_length(n)
+    hi, lo = pad_lanes(hi, lo, m)
+    if tag is not None:
+        tag = jnp.pad(tag.astype(jnp.int32), (0, m - n))
+    ho, lo_out, perm, tag_out = sorted_lanes(
+        hi, lo, tag, tile=tile, interpret=interpret, tag_bits=tag_bits)
+    if tag is None:
+        return ho[:n], lo_out[:n], perm[:n]
+    return ho[:n], lo_out[:n], perm[:n], tag_out[:n]
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))

@@ -90,6 +90,43 @@ def test_sort_pairs_parity_tile_straddling(n):
                                       np.asarray(getattr(rp, name)))
 
 
+@pytest.mark.parametrize("n,nflags,num_keys", [
+    (1, 1, 2), (300, 2, 2), (1025, 3, 2), (4097, 2, 2), (300, 3, 1)])
+def test_sort_pairs_flags_parity(n, nflags, num_keys):
+    """Flags co-sorted by the sort itself (the index lane's low bits on
+    pallas, sort operands on xla): bitwise equal on both backends, equal
+    to ``flag[perm]``, and the rest of the sort as without them."""
+    rng = np.random.default_rng(n + nflags)
+    k2 = jnp.asarray(rng.integers(0, max(n // 16, 2), n), jnp.int32)
+    mk = jnp.asarray(rng.integers(0, 3, n), jnp.int32)
+    flags = tuple(jnp.asarray(rng.random(n) < 0.5) for _ in range(nflags))
+    payload = {"v": jnp.asarray(rng.integers(-9, 9, (n, 2)), jnp.float32)}
+    plain = ops.sort_pairs(k2, mk, payload, num_keys=num_keys,
+                           backend="xla")
+    rx, rp = _both(lambda bk: ops.sort_pairs(
+        k2, mk, payload, num_keys=num_keys, flags=flags, backend=bk))
+    perm = np.asarray(plain.perm)
+    for r in (rx, rp):
+        for name in ("k2", "mk", "perm"):
+            np.testing.assert_array_equal(np.asarray(getattr(r, name)),
+                                          np.asarray(getattr(plain, name)),
+                                          err_msg=name)
+        np.testing.assert_array_equal(np.asarray(r.payload["v"]),
+                                      np.asarray(plain.payload["v"]))
+        assert len(r.flags) == nflags
+        for got, f in zip(r.flags, flags):
+            assert got.dtype == jnp.bool_
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(f)[perm])
+
+
+def test_sort_pairs_too_many_flags_refused():
+    k2 = jnp.zeros(4, jnp.int32)
+    flags = (jnp.ones(4, bool),) * (ops.MAX_FLAGS + 1)
+    for bk in ("xla", "pallas"):
+        with pytest.raises(ValueError):
+            ops.sort_pairs(k2, k2, flags=flags, backend=bk)
+
+
 def test_sort_pairs_single_key_stable():
     rng = np.random.default_rng(0)
     n = 129                                     # non-power-of-two

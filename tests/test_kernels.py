@@ -200,6 +200,75 @@ class TestSortMultiTile:
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def _tag_case(name):
+    """(hi, lo, tile) of one tagged-sort case."""
+    rng = np.random.default_rng(len(name))
+    n, tile = {"single_tile": (700, 4096), "multi_tile": (4 * MIN_TILE,
+                                                          MIN_TILE),
+               "pad_rows": (2 * MIN_TILE + 37, MIN_TILE),
+               "all_equal": (5 * MIN_TILE // 2, MIN_TILE),
+               "ties": (3 * MIN_TILE + 7, MIN_TILE)}[name]
+    hi = rng.integers(0, max(n // 8, 2), n)
+    lo = rng.integers(0, 5, n)
+    if name == "all_equal":
+        hi, lo = np.zeros(n), np.zeros(n)
+    elif name == "ties":                 # few keys, extremes, the pad key
+        hi = rng.choice(np.array([-2**31, 0, 2**31 - 1]), n)
+        lo = np.full(n, 2**31 - 1)
+    return jnp.asarray(hi, jnp.int32), jnp.asarray(lo, jnp.int32), tile
+
+
+class TestSortTag:
+    """A tag under the index lane leaves the sort as it was and comes
+    back as ``tag[perm]``."""
+
+    @pytest.mark.parametrize("tag_bits", [1, 2, 3])
+    @pytest.mark.parametrize("case", ["single_tile", "multi_tile",
+                                      "pad_rows", "all_equal", "ties"])
+    def test_tag_rides_the_index_lane(self, case, tag_bits):
+        hi, lo, tile = _tag_case(case)
+        rng = np.random.default_rng(tag_bits)
+        tag = jnp.asarray(rng.integers(0, 1 << tag_bits, hi.shape[0]),
+                          jnp.int32)
+        want = sort_lex_pallas(hi, lo, tile=tile)
+        *got, got_tag = sort_lex_pallas(hi, lo, tile=tile, tag=tag,
+                                        tag_bits=tag_bits)
+        for a, b, name in zip(got, want, ("hi", "lo", "perm")):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=name)
+        np.testing.assert_array_equal(
+            np.asarray(got_tag), np.asarray(tag)[np.asarray(want[2])])
+
+    def test_widest_tag_at_the_limit(self):
+        """2,048 padded rows leave 20 tag bits: the largest index lane
+        value is exactly int32 max, and every bit comes back."""
+        n, bits = 2048, 20
+        rng = np.random.default_rng(20)
+        hi = jnp.asarray(rng.integers(0, 50, n), jnp.int32)
+        lo = jnp.zeros(n, jnp.int32)
+        tag = jnp.asarray(rng.integers(0, 1 << bits, n), jnp.int32)
+        _, _, perm, got = sort_lex_pallas(hi, lo, tag=tag, tag_bits=bits)
+        np.testing.assert_array_equal(np.asarray(perm),
+                                      np.asarray(sort_lex_ref(hi, lo)[2]))
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(tag)[np.asarray(perm)])
+
+    @pytest.mark.parametrize("n,bits", [(2**29 + 1, 2), (2049, 20)],
+                             ids=["merge_flags", "one_row_past"])
+    def test_length_past_the_limit_refused(self, n, bits):
+        lane = jax.ShapeDtypeStruct((n,), jnp.int32)
+        with pytest.raises(ValueError, match="overflow"):
+            jax.eval_shape(lambda hi, lo, t: sort_lex_pallas(
+                hi, lo, tag=t, tag_bits=bits), lane, lane, lane)
+
+    def test_tag_without_bits_refused(self):
+        hi = jnp.zeros(8, jnp.int32)
+        with pytest.raises(ValueError):
+            sort_lex_pallas(hi, hi, tag=hi)
+        with pytest.raises(ValueError):
+            sort_lex_pallas(hi, hi, tag_bits=2)
+
+
 class TestSegmentReduceEdgeCases:
     """n=0 / num_segments=0 must return empty results, not crash."""
 
@@ -452,6 +521,69 @@ class TestRoute:
         for dims in shapes:
             rows = np.prod([int(d) for d in dims.split(",") if d])
             assert rows <= key_cap, f"route gathers [{dims}]"
+
+
+def _gathered_shuffle_reduce(reducer, k2, mk, values, valid, sign,
+                             affected_keys, backend):
+    """The reference merge: ``valid`` and ``sign`` gathered through the
+    sort permutation as payload leaves, the rest as in
+    ``ops.shuffle_reduce``."""
+    from repro.kernels import ops
+    n, key_cap = k2.shape[0], affected_keys.shape[0]
+    k2m = jnp.where(valid, k2, jnp.int32(2**31 - 1))
+    res = ops.sort_pairs(k2m, mk, (values, valid, sign), num_keys=2,
+                         backend=backend)
+    vals_s, valid_s, sign_s = res.payload
+    is_last = ((jnp.arange(n) == n - 1) | (jnp.roll(res.k2, -1) != res.k2)
+               | (jnp.roll(res.mk, -1) != res.mk))
+    live = valid_s & is_last & (sign_s > 0)
+    local, in_set = ops._route(res.k2, affected_keys)
+    acc, counts = ops.segment_reduce(reducer, local, vals_s, live & in_set,
+                                     key_cap, backend=backend)
+    return ops.ShuffleReduced(res.k2, res.mk, vals_s, live, res.perm, acc,
+                              counts)
+
+
+def _flag_case(name):
+    """A route case, or tombstones and invalid rows interleaved over
+    repeated (k2, mk) pairs, so that every flag decides some row."""
+    if name != "tombstones_interleaved":
+        return _route_case(name)
+    rng = np.random.default_rng(7)
+    n, cap = 300, 64
+    k2 = rng.integers(0, 20, n).astype(np.int32)
+    mk = rng.integers(0, 3, n).astype(np.int32)
+    valid = np.arange(n) % 3 != 1
+    sign = np.where(np.arange(n) % 2 == 0, 1, -1).astype(np.int8)
+    vals = rng.integers(-20, 20, (n, 2)).astype(np.float32)
+    keys = np.full(cap, 2**31 - 1, np.int32)
+    aff = np.unique(k2[valid])
+    keys[:aff.size] = aff
+    return tuple(jnp.asarray(a) for a in (k2, mk, vals, valid, sign, keys))
+
+
+class TestMergeFlags:
+    """``ops.shuffle_reduce`` with ``valid`` and ``sign > 0`` carried by
+    the sort equals the composition that gathered them, bitwise on every
+    output and on both backends."""
+
+    class _Sum:
+        kind = "sum"
+
+    @pytest.mark.parametrize("backend", ["xla", "pallas"])
+    @pytest.mark.parametrize("case", ROUTE_CASES + ["tombstones_interleaved"])
+    def test_matches_gathered_flags(self, case, backend):
+        from repro.kernels import ops
+        args = _flag_case(case)
+        got = ops.shuffle_reduce(self._Sum(), *args, backend=backend)
+        want = _gathered_shuffle_reduce(self._Sum(), *args, backend)
+        for name in ops.ShuffleReduced._fields:
+            np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                          np.asarray(getattr(want, name)),
+                                          err_msg=name)
+        if case == "tombstones_interleaved":     # the flags decide rows
+            live = np.asarray(got.live)
+            assert 0 < live.sum() < (np.asarray(args[3])).sum()
 
 
 class TestSpmv:

@@ -11,6 +11,7 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +67,14 @@ def test_sort_lex(one_chip, n):
              lane, lane)
 
 
+@pytest.mark.parametrize("n", [SORT_TILE, 1 << 16],
+                         ids=["single_tile", "multi_tile"])
+def test_sort_lex_tagged(one_chip, n):
+    lane = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    _compile(lambda hi, lo, tag: sort_lex_pallas(
+        hi, lo, interpret=False, tag=tag, tag_bits=2), lane, lane, lane)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
 def test_segment_sum(one_chip, dtype):
     n, d, k = 1 << 16, 8, 100_000
@@ -115,7 +124,9 @@ def test_merge_at_benchmark_bucket_fits_one_chip(one_chip, monkeypatch):
     benchmark's bucket: 2^23 rows of wordcount's [rows, 1] value column
     into 1,024 key slots.  The compiler refuses a program past the chip's
     memory, where the padded layout of a [rows, 1] column would take it if
-    it spread to the producers of the reduce's ids and mask."""
+    it spread to the producers of the reduce's ids and mask.  The merge's
+    ``valid`` and ``sign`` ride through the sort in its index lane, so no
+    gather of a one-byte lane through the permutation is left."""
     from repro.core.incremental import _merge_reduce
     from repro.core.kvstore import Edges, sum_reducer
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")   # native kernels
@@ -128,6 +139,9 @@ def test_merge_at_benchmark_bucket_fits_one_chip(one_chip, monkeypatch):
     keys = jax.ShapeDtypeStruct((key_cap,), jnp.int32, sharding=one_chip)
     compiled = _merge_reduce.lower(sum_reducer(), key_cap, "pallas",
                                    combined, keys).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    flag_gathers = re.findall(rf"= (?:s8|pred)\[{n}\]\S* gather\(", text)
+    assert not flag_gathers, flag_gathers
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
